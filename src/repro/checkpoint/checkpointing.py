@@ -46,6 +46,7 @@ class CheckpointManager:
         self.keep = keep
         os.makedirs(directory, exist_ok=True)
         self._pool = FCFSPool(2, "ckpt-io") if async_writes else None
+        self._writes: list = []   # handles of async writes not yet waited
         self._lock = threading.Lock()
 
     # -- save ---------------------------------------------------------------
@@ -69,7 +70,8 @@ class CheckpointManager:
             self._gc()
 
         if self._pool:
-            self._pool.submit(write_all, name=f"ckpt-{step}")
+            self._writes.append(
+                self._pool.submit(write_all, name=f"ckpt-{step}"))
         else:
             write_all()
         if self.sink is not None:  # analyzable checkpoint via SAVIME
@@ -80,8 +82,15 @@ class CheckpointManager:
         return cdir
 
     def wait(self) -> None:
+        """Block until every save so far is written; raise if any failed."""
         if self._pool:
             self._pool.sync()
+            writes, self._writes = self._writes, []
+            failed = [h for h in writes if h.error is not None]
+            if failed:
+                raise RuntimeError(
+                    f"{len(failed)} checkpoint write(s) failed, first "
+                    f"{failed[0].name}: {failed[0].error}") from failed[0].error
         if self.sink:
             self.sink.flush()
 

@@ -38,6 +38,7 @@ import hashlib
 import mmap
 import os
 import secrets
+import tempfile
 import threading
 from typing import Optional
 
@@ -112,13 +113,14 @@ class PageStore:
     }
 
     def __init__(self, capacity: int, page_bytes: int = DEFAULT_PAGE_BYTES,
-                 mem_dir: str = "/dev/shm", spill_dir: str = "/tmp",
+                 mem_dir: str = "/dev/shm", spill_dir: Optional[str] = None,
                  dedup: bool = False):
         if page_bytes < 1:
             raise ValueError(f"page_bytes must be >= 1, got {page_bytes}")
         self.page_bytes = page_bytes
         self.n_frames = max(1, capacity // page_bytes)
         self.dedup = dedup
+        spill_dir = spill_dir or tempfile.gettempdir()
         os.makedirs(mem_dir, exist_ok=True)
         os.makedirs(spill_dir, exist_ok=True)
         self.spill_dir = spill_dir

@@ -36,6 +36,7 @@ import math
 import os
 import secrets
 import socket
+import tempfile
 import threading
 import time
 import zlib
@@ -119,7 +120,8 @@ class StagingServer:
         self.savime_addr = savime_addr
         uid = f"{os.getpid()}-{secrets.token_hex(3)}"
         self.mem_dir = mem_dir or f"/dev/shm/staging-{uid}"
-        self.disk_dir = disk_dir or f"/tmp/staging-{uid}"
+        self.disk_dir = disk_dir or os.path.join(tempfile.gettempdir(),
+                                                 f"staging-{uid}")
         os.makedirs(self.mem_dir, exist_ok=True)
         os.makedirs(self.disk_dir, exist_ok=True)
         self.mem_capacity = mem_capacity

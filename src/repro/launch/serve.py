@@ -18,6 +18,7 @@ from repro import analysis, transport
 from repro.configs import get_config
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models import Model
+from repro.runtime import enable_compile_cache
 from repro.train import ServeSetup
 
 
@@ -30,7 +31,9 @@ def build_mesh(spec: str):
     return make_debug_mesh(*parts)
 
 
-def main() -> None:
+def main(argv=None) -> dict:
+    """Serve one batch; returns a summary of what was generated, timed and
+    (with --intransit --analyzer) queried back from SAVIME."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -90,7 +93,7 @@ def main() -> None:
                          "string ('seed=42;drop:op=stripe,prob=0.01;"
                          "kill:target=staging:0,at_s=0.5') or a JSON plan "
                          "file; exercises retry/replay (DESIGN.md §15)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.analyzer and not args.intransit:
         ap.error("--analyzer requires --intransit")
     if (args.tenant or args.quota_mb) and not args.pool:
@@ -98,6 +101,7 @@ def main() -> None:
     if args.pool and args.transport != "rdma_staged":
         ap.error("--pool requires the rdma_staged transport")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
@@ -183,6 +187,13 @@ def main() -> None:
 
     key = jax.random.PRNGKey(2)
     with jax.set_mesh(mesh):
+        # compile both steps before the timed run
+        t0 = time.perf_counter()
+        _, warm = prefill(params, {"tokens": prompts})
+        jax.block_until_ready(decode(params, warm, {
+            "tokens": prompts[:, :1], "pos": jnp.full((B,), S, jnp.int32)}))
+        t_compile = time.perf_counter() - t0
+
         t0 = time.perf_counter()
         logits, cache = prefill(params, {"tokens": prompts})
         jax.block_until_ready(logits)
@@ -211,11 +222,16 @@ def main() -> None:
 
     gen = jnp.concatenate(out, axis=1)
     lat_ms = np.asarray(lat) * 1e3
-    print(f"[serve] prefill {t_prefill * 1e3:.0f} ms; decode p50 "
+    print(f"[serve] compile {t_compile:.1f} s; "
+          f"prefill {t_prefill * 1e3:.0f} ms; decode p50 "
           f"{np.percentile(lat_ms, 50):.1f} ms/tok, p99 "
           f"{np.percentile(lat_ms, 99):.1f} ms/tok "
           f"({B * 1e3 / np.mean(lat_ms):.1f} tok/s aggregate)")
     print(f"[serve] sample (req 0): {gen[0, :16].tolist()}")
+    summary = {"prompts": np.asarray(prompts), "tokens": np.asarray(gen),
+               "last_logits": np.asarray(logits), "compile_s": t_compile,
+               "prefill_ms": t_prefill * 1e3,
+               "decode_ms": lat_ms}
     if sink is not None:
         sink.flush()
         if args.analyzer:
@@ -232,6 +248,9 @@ def main() -> None:
                 s = a.summary()
                 print(f"[serve] analyzer[{s.analyzer}] over "
                       f"{res.shape} staged latencies: {s.payload}")
+                summary["staged_decode_ms"] = res.array
+                summary["analyzer"] = s.payload
+        summary["staged_bytes"] = sink.staged_bytes
         sink.close()
         if fault_sched is not None:
             from repro.faults import uninstall
@@ -247,6 +266,7 @@ def main() -> None:
         else:
             staging.stop()
             savime.stop()
+    return summary
 
 
 if __name__ == "__main__":

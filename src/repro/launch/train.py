@@ -9,6 +9,8 @@ query while training).
 from __future__ import annotations
 
 import argparse
+import os
+import tempfile
 import time
 
 import jax
@@ -21,7 +23,7 @@ from repro.core import InTransitConfig, InTransitSink, SavimeServer, StagingServ
 from repro.data import DataConfig, SyntheticLM, device_put_batch
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.models import Model
-from repro.runtime import Supervisor, SupervisorConfig
+from repro.runtime import Supervisor, SupervisorConfig, enable_compile_cache
 from repro.train import TrainConfig, TrainSetup
 
 
@@ -36,7 +38,10 @@ def build_mesh(spec: str):
     return make_debug_mesh(parts[1], parts[2], pod=parts[0])
 
 
-def main() -> None:
+def main(argv=None, before_close=None) -> dict:
+    """Train; returns a summary of the run. With --intransit,
+    ``before_close(state, savime_addr)`` runs once everything staged is
+    queryable and before the servers stop."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",
@@ -47,7 +52,8 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
     ap.add_argument("--lr", type=float, default=3e-3)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro-ckpt")
+    ap.add_argument("--ckpt-dir", default=os.path.join(
+        tempfile.gettempdir(), "repro-ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--intransit", action="store_true",
                     help="stage per-step diagnostics into SAVIME")
@@ -91,8 +97,9 @@ def main() -> None:
                          "string ('seed=42;drop:op=stripe,prob=0.01;"
                          "kill:target=staging:0,at_s=0.5') or a JSON plan "
                          "file; exercises retry/replay (DESIGN.md §15)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
@@ -142,7 +149,7 @@ def main() -> None:
               f"--> SAVIME {savime.addr}")
 
     ckpt = CheckpointManager(args.ckpt_dir, sink=sink)
-    sup = Supervisor(jax.jit(setup.step_fn(), donate_argnums=(0,)), ckpt,
+    sup = Supervisor(setup.jitted(), ckpt,
                      SupervisorConfig(ckpt_every=args.ckpt_every))
 
     dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,
@@ -164,10 +171,14 @@ def main() -> None:
     print(f"[train] {args.steps} steps in {dt:.1f}s "
           f"({dt / max(args.steps, 1) * 1e3:.0f} ms/step) "
           f"loss {losses[0]:.3f} -> {losses[-1]:.3f}")
+    summary = {"losses": losses, "restarts": sup.restarts, "seconds": dt}
     if sink is not None:
         sink.flush()
         print(f"[train] staged {sink.staged_arrays} arrays, "
               f"{sink.staged_bytes / 1e6:.1f} MB into SAVIME")
+        summary["staged_bytes"] = sink.staged_bytes
+        if before_close is not None:
+            before_close(state, savime.addr)
         sink.close()
         if fault_sched is not None:
             from repro.faults import uninstall
@@ -175,6 +186,7 @@ def main() -> None:
             uninstall()
         staging.stop()
         savime.stop()
+    return summary
 
 
 if __name__ == "__main__":

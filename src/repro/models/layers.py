@@ -51,8 +51,11 @@ class ParamSpec:
             d_state = self.shape[-1]
             a = jnp.tile(jnp.arange(1, d_state + 1, dtype=jnp.float32), self.shape[:-1] + (1,))
             return jnp.log(a).astype(self.dtype)
-        # truncated-normal, fan-in scaled
-        fan_in = self.shape[0] if len(self.shape) >= 2 else max(self.shape[-1], 1)
+        # truncated-normal, fan-in scaled; a stacked `layers` axis is not
+        # part of the fan-in
+        dims = self.shape[1:] if self.logical_axes[:1] == ("layers",) \
+            else self.shape
+        fan_in = dims[0] if len(dims) >= 2 else max(dims[-1], 1)
         std = self.scale / np.sqrt(fan_in)
         arr = (std * jax.random.truncated_normal(
             key, -2.0, 2.0, self.shape)).astype(self.dtype)

@@ -114,7 +114,11 @@ def _chunked_xent(params, hidden, targets, mask, cfg, chunk: int):
         lg = unembed(params["embed"], h, cfg.tie_embeddings)
         lg = softcap(lg, cfg.logit_softcap).astype(jnp.float32)
         logz = jax.nn.logsumexp(lg, axis=-1)                      # (B,c)
-        tgt = jnp.take_along_axis(lg, t[..., None], axis=-1)[..., 0]
+        # one-hot contraction, not take_along_axis: a gather along the
+        # model-sharded vocab axis aborts XLA's SPMD partitioner inside
+        # the pod-manual shard_map of the compressed train step
+        tgt = jnp.sum(lg * jax.nn.one_hot(t, lg.shape[-1], dtype=lg.dtype),
+                      axis=-1)
         nll_sum, z2_sum, m_sum = carry
         nll_sum = nll_sum + jnp.sum((logz - tgt) * m)
         z2_sum = z2_sum + jnp.sum(jnp.square(logz) * m)

@@ -7,7 +7,7 @@ hop rides slow DCI. We compress exactly that hop:
 
   * train_step computes grads with the batch sharded over (`data` only) —
     pjit's autodiff all-reduces over `data` within each pod;
-  * a shard_map over {`pod`} (other axes stay auto) then performs an int8
+  * a shard_map (`pod_reduce`) then performs an int8
     block-quantized reduce-scatter + all-gather over the pod axis with
     per-(pod, block) scales and local error-feedback accumulation, so the
     bf16->int8 quantization error is re-injected next step (convergence-
@@ -67,50 +67,53 @@ def _unflatten(flat2d: jax.Array, pad: int, tree: PyTree) -> PyTree:
     return jax.tree.unflatten(treedef, out)
 
 
+def pod_reduce(flat: jax.Array, err: jax.Array, n_pods: int):
+    """int8 ring reduce-scatter + all-gather over `pod` with error feedback.
+
+    Runs inside a shard_map that is manual over `pod`. `flat`, `err`: this
+    pod's (n_blocks, QBLOCK) f32 gradient and residual; n_blocks must be a
+    multiple of n_pods (`_flatten` and `error_state` pad to it). Returns
+    (mean over pods, new residual).
+    """
+    g = flat + err                                    # error feedback in
+    q, s = _quant_blocks(g)
+    new_err = g - q.astype(jnp.float32) * s[:, None]  # residual out
+    shard_rows = flat.shape[0] // n_pods
+    mine = jax.lax.axis_index("pod")
+
+    def rows_of(qr, sr):
+        r = jax.lax.dynamic_slice_in_dim(qr, mine * shard_rows, shard_rows, 0)
+        c = jax.lax.dynamic_slice_in_dim(sr, mine * shard_rows, shard_rows, 0)
+        return r.astype(jnp.float32) * c[:, None]
+
+    # reduce-scatter over pods: pod p owns rows [p*shard_rows, ...)
+    acc = rows_of(q, s)
+    qr, sr = q, s
+    perm = [(i, (i + 1) % n_pods) for i in range(n_pods)]
+    for _ in range(1, n_pods):
+        qr = jax.lax.ppermute(qr, "pod", perm)        # int8 on the wire
+        sr = jax.lax.ppermute(sr, "pod", perm)
+        acc = acc + rows_of(qr, sr)
+    acc = acc / n_pods
+    # all-gather the reduced shards (int8 on the wire again)
+    qa, sa = _quant_blocks(acc)
+    q_all = jax.lax.all_gather(qa, "pod", axis=0, tiled=True)
+    s_all = jax.lax.all_gather(sa, "pod", axis=0, tiled=True)
+    return q_all.astype(jnp.float32) * s_all[:, None], new_err
+
+
 def compressed_pod_allreduce(grads: PyTree, err: jax.Array, mesh):
     """Mean-reduce `grads` over the `pod` mesh axis with int8 compression +
     error feedback. `err`: f32 (n_blocks, QBLOCK) residual carried across
     steps (init zeros via `error_state`). Returns (reduced_grads, new_err).
+
+    The shard_map is manual over every mesh axis with replicated specs:
+    the body only talks over `pod`, and the other axes hold copies.
     """
     n_pods = mesh.shape["pod"]
     flat, pad = _flatten(grads, n_pods)
-    n_blocks = flat.shape[0]
-
-    def body(g, e):
-        # g, e: per-pod (n_blocks, QBLOCK) f32 (manual over `pod` only)
-        g = g + e                                     # error feedback in
-        q, s = _quant_blocks(g)
-        new_e = g - q.astype(jnp.float32) * s[:, None]  # residual out
-        # reduce-scatter over pods: pod p owns rows [p::n_pods]
-        mine = jax.lax.axis_index("pod")
-        # exchange int8 shards: psum of dequantized own-shard contributions
-        # via ppermute ring (int8 on the wire)
-        shard_rows = n_blocks // n_pods
-        my_rows = jax.lax.dynamic_slice_in_dim(q, mine * shard_rows,
-                                               shard_rows, 0)
-        my_scale = jax.lax.dynamic_slice_in_dim(s, mine * shard_rows,
-                                                shard_rows, 0)
-        acc = my_rows.astype(jnp.float32) * my_scale[:, None]
-        qr, sr = q, s
-        for hop in range(1, n_pods):
-            perm = [(i, (i + 1) % n_pods) for i in range(n_pods)]
-            qr = jax.lax.ppermute(qr, "pod", perm)        # int8 wire
-            sr = jax.lax.ppermute(sr, "pod", perm)
-            rows = jax.lax.dynamic_slice_in_dim(qr, mine * shard_rows,
-                                                shard_rows, 0)
-            sc = jax.lax.dynamic_slice_in_dim(sr, mine * shard_rows,
-                                              shard_rows, 0)
-            acc = acc + rows.astype(jnp.float32) * sc[:, None]
-        acc = acc / n_pods
-        # all-gather the reduced shards (int8 wire again)
-        qa, sa = _quant_blocks(acc)
-        q_all = jax.lax.all_gather(qa, "pod", tiled=True)   # (n_blocks, QB)
-        s_all = jax.lax.all_gather(sa, "pod", tiled=True)
-        out = q_all.astype(jnp.float32) * s_all[:, None]
-        return out, new_e
-
-    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
-                       out_specs=(P(), P()), axis_names={"pod"},
+    fn = jax.shard_map(functools.partial(pod_reduce, n_pods=n_pods),
+                       mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()),
                        check_vma=False)
     reduced, new_err = fn(flat, err)
     return _unflatten(reduced, pad, grads), new_err

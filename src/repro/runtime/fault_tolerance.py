@@ -63,6 +63,7 @@ class Supervisor:
         and continues. fail_at injects failures (tests/examples)."""
         step_idx = int(jax.device_get(state["step"])) \
             if isinstance(state, dict) and "step" in state else 0
+        saved = None
         while step_idx < n_steps:
             batch = next(batches)
             try:
@@ -86,10 +87,12 @@ class Supervisor:
                 continue
             if step_idx % self.cfg.ckpt_every == 0:
                 self.ckpt.save(state, step_idx)
+                saved = step_idx
             self.metrics_log.append(
                 {k: float(v) for k, v in metrics.items()
                  if hasattr(v, "shape") and getattr(v, "shape", None) == ()})
-        self.ckpt.save(state, step_idx)
+        if saved != step_idx:
+            self.ckpt.save(state, step_idx)
         self.ckpt.wait()
         return state
 

@@ -82,9 +82,10 @@ class TrainSetup:
         return abstract_params(self.state_specs())
 
     def init_state(self, key: jax.Array) -> dict:
-        st = init_params(self.state_specs(), key)
-        # params need real random init (init_params gave them random too)
-        return st
+        """Materialize the state already laid out on the mesh (an unsharded
+        full-width state would not fit one chip)."""
+        init = functools.partial(init_params, self.state_specs())
+        return jax.jit(init, out_shardings=self.state_shardings())(key)
 
     # -- the step -----------------------------------------------------------
     def _loss(self, params: PyTree, batch: dict):
@@ -127,7 +128,8 @@ class TrainSetup:
                     # _flatten row-pads to a multiple of n_pods (ring RS
                     # needs n|rows), matching error_state's layout.
                     flat, pad = grad_compress._flatten(grads, n_pods)
-                    red, new_err = _pod_reduce(flat, err_pod[0], n_pods)
+                    red, new_err = grad_compress.pod_reduce(
+                        flat, err_pod[0], n_pods)
                     loss = jax.lax.pmean(loss, "pod")
                     metrics = jax.tree.map(
                         lambda m: jax.lax.pmean(m, "pod"), metrics)
@@ -216,31 +218,3 @@ class TrainSetup:
 def _metric_tree():
     return {"nll": 0.0, "z2": 0.0, "moe_lb": 0.0, "moe_z": 0.0}
 
-
-def _pod_reduce(flat: jax.Array, err: jax.Array, n_pods: int):
-    """int8 ring reduce-scatter + all-gather over `pod` with error feedback
-    (runs inside a shard_map manual over {pod})."""
-    g = flat + err
-    q, s = grad_compress._quant_blocks(g)
-    new_err = g - q.astype(jnp.float32) * s[:, None]
-    n_blocks = flat.shape[0]
-    shard_rows = n_blocks // n_pods
-    mine = jax.lax.axis_index("pod")
-
-    def rows_of(qr, sr):
-        r = jax.lax.dynamic_slice_in_dim(qr, mine * shard_rows, shard_rows, 0)
-        c = jax.lax.dynamic_slice_in_dim(sr, mine * shard_rows, shard_rows, 0)
-        return r.astype(jnp.float32) * c[:, None]
-
-    acc = rows_of(q, s)
-    qr, sr = q, s
-    perm = [(i, (i + 1) % n_pods) for i in range(n_pods)]
-    for _ in range(1, n_pods):
-        qr = jax.lax.ppermute(qr, "pod", perm)        # int8 on the wire
-        sr = jax.lax.ppermute(sr, "pod", perm)
-        acc = acc + rows_of(qr, sr)
-    acc = acc / n_pods
-    qa, sa = grad_compress._quant_blocks(acc)
-    q_all = jax.lax.all_gather(qa, "pod", axis=0, tiled=True)
-    s_all = jax.lax.all_gather(sa, "pod", axis=0, tiled=True)
-    return q_all.astype(jnp.float32) * s_all[:, None], new_err

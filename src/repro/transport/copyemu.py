@@ -43,6 +43,7 @@ import secrets
 import shutil
 import socket
 import struct
+import tempfile
 import threading
 import time
 import zlib
@@ -409,7 +410,8 @@ class _ScpTransport(_CopyTransportBase):
     def open(self) -> None:
         uid = secrets.token_hex(3)
         self._store = (f"/dev/shm/scp-{uid}" if self.storage == "mem"
-                       else f"/tmp/scp-{uid}")
+                       else os.path.join(tempfile.gettempdir(),
+                                         f"scp-{uid}"))
         os.makedirs(self._store, exist_ok=True)
         self._srv = _CopyServer(
             store_dir=self._store, fsync=(self.storage == "disk"),

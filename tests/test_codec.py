@@ -18,10 +18,7 @@ import threading
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:
-    from _hypothesis_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro import codec as codec_mod
 from repro.core import wire

@@ -206,6 +206,30 @@ def test_supervisor_restores_from_checkpoint(tmp_path):
     assert np.allclose(np.asarray(out["w"]), 7.0)
 
 
+def test_supervisor_saves_each_checkpoint_step_once(tmp_path):
+    """A run whose last step is a checkpoint step saves it once, not a
+    second time as the final checkpoint."""
+    import jax.numpy as jnp
+    from repro.checkpoint import CheckpointManager
+    from repro.runtime import Supervisor, SupervisorConfig
+
+    class Recording(CheckpointManager):
+        def save(self, state, step):
+            self.steps.append(step)
+            return super().save(state, step)
+
+    def step_fn(state, batch):
+        return {"step": state["step"] + 1}, {}, {}
+
+    for n_steps, want in ((4, [2, 4]), (5, [2, 4, 5])):
+        ckpt = Recording(str(tmp_path / str(n_steps)), async_writes=False)
+        ckpt.steps = []
+        Supervisor(step_fn, ckpt, SupervisorConfig(ckpt_every=2)).run(
+            {"step": jnp.zeros((), jnp.int32)}, iter(lambda: {}, None),
+            n_steps)
+        assert ckpt.steps == want
+
+
 def test_supervisor_restart_budget_exceeded(tmp_path):
     """Burning through max_restarts raises the typed error, and the
     message carries the last committed checkpoint step (enough to resume
@@ -290,6 +314,54 @@ def test_checkpoint_reshard_roundtrip(tmp_path):
     back = ckpt.restore(abstract)
     assert all(np.array_equal(x, y) for x, y in
                zip(jax.tree.leaves(state), jax.tree.leaves(back)))
+
+
+def test_checkpoint_wait_raises_on_failed_write(tmp_path, monkeypatch):
+    """A failed async checkpoint write surfaces at wait(), not as a
+    silent pool counter."""
+    import jax.numpy as jnp
+    from repro.checkpoint import CheckpointManager, checkpointing
+
+    def broken_save(*a, **kw):
+        raise OSError("disk full")
+
+    ckpt = CheckpointManager(str(tmp_path), async_writes=True)
+    monkeypatch.setattr(checkpointing.np, "save", broken_save)
+    ckpt.save({"a": jnp.ones(4)}, 1)
+    with pytest.raises(RuntimeError, match="ckpt-1.*disk full"):
+        ckpt.wait()
+    monkeypatch.undo()
+    ckpt.save({"a": jnp.ones(4)}, 2)
+    ckpt.wait()                      # the failure was reported once
+    assert ckpt.latest_step() == 2
+
+
+@pytest.mark.parametrize("env_dir", [None, "elsewhere"])
+def test_compile_cache_dir(monkeypatch, tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR decides where the cache goes; without it
+    the cache is the fixed .jax_cache/ at the repository root."""
+    import jax
+    from repro.runtime import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / env_dir))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_dir:
+            assert got == str(tmp_path / env_dir)
+            assert jax.config.jax_compilation_cache_dir == was
+        else:
+            want = os.path.join(os.path.dirname(__file__), "..", ".jax_cache")
+            assert os.path.samefile(os.path.dirname(got),
+                                    os.path.dirname(want))
+            assert got.endswith(".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
 
 
 def test_elastic_mesh_plan():
