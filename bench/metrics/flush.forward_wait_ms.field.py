@@ -1,0 +1,14 @@
+"""Mean time per flush that ``InTransitSink.flush`` waits for staging to
+forward the group to SAVIME: the program's ``session.drain`` spans inside
+``sink.flush``, summed over the window's groups."""
+
+
+def read(run):
+    try:
+        from repro import obs
+    except ImportError:          # a program without spans
+        return None
+    flushes = {s.id for s in obs.spans("sink.flush")}
+    d = [s.seconds for s in obs.spans("session.drain") if s.parent in flushes]
+    groups = run["record"].get("groups")
+    return 1e3 * sum(d) / groups if d and groups else None

@@ -22,6 +22,7 @@ from typing import Optional, Union
 
 import numpy as np
 
+from repro import obs
 from repro.core import wire
 from repro.core.blocks import plan_blocks
 from repro.core.queues import FCFSPool, TaskHandle
@@ -177,10 +178,12 @@ class Communicator:
         Serialized under the codec lock: chained codecs (delta-rle) must
         observe submissions in order even when I/O threads race."""
         t0 = time.perf_counter()
-        with self._codec_lock:
+        with obs.span("codec.encode", ds=name, bytes_in=buf.nbytes) as sp, \
+                self._codec_lock:
             payload, meta = self._codec.encode(buf, dtype=dtype, key=name)
             enc = payload if isinstance(payload, np.ndarray) else \
                 np.frombuffer(memoryview(payload).cast("B"), np.uint8)
+            sp.set(bytes_out=enc.nbytes)
             c = self._codec_counts
             c["raw_bytes"] += buf.nbytes
             c["wire_bytes"] += enc.nbytes
@@ -212,7 +215,12 @@ class Communicator:
                     # re-admit on every attempt: after a backend fail-out
                     # the gateway routes the retry onto the rebuilt ring
                     tgt = self._gateway.admit(name, buf.nbytes, epoch=epoch)
-                return self._send_once(name, dtype, buf, tgt, cinfo, epoch)
+                with obs.span("client.send", ds=name, bytes=buf.nbytes,
+                              blocks=len(plan_blocks(buf.nbytes,
+                                                     self.block_size)),
+                              attempt=attempt.index):
+                    return self._send_once(name, dtype, buf, tgt, cinfo,
+                                           epoch)
             except (ConnectionError, TimeoutError, OSError) as e:
                 self._socks.invalidate(tgt or self.addr)
                 attempt.backoff(e)   # raises RetryExhausted when spent

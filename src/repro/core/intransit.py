@@ -25,6 +25,7 @@ from typing import Any, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.analysis.query import CreateTar, LoadSubtar
 from repro.core.tars import Attribute, Dimension
 from repro.transport import TransferSession, TransportConfig
@@ -140,31 +141,34 @@ class InTransitSink:
     def stage_array(self, name: str, arr: Any, step: int = 0) -> None:
         """Non-blocking: device->host copy + enqueue. `arr` is a jax or
         numpy array; the write itself happens on libstaging I/O threads."""
-        x = np.asarray(arr)                   # device_get for jax arrays
         tar = f"{self.cfg.tar_prefix}_{name}"
-        quantized = self.cfg.quantize == "int8" and x.dtype.kind == "f"
-        self._ensure_tar(tar, x.shape, str(x.dtype), quantized)
         ds_name = f"{tar}__{step}"
-        if quantized:
-            q, scale = quantize_int8_np(x, self.cfg.quant_block)
-            self.session.write(ds_name, q, dtype="int8")
-            self.session.write(ds_name + "s", scale, dtype="float32")
-            with self._lock:
-                self._pending.append(LoadSubtar(
-                    tar, ds_name, (step, 0), (1, q.size), "v"))
-                self._pending.append(LoadSubtar(
-                    f"{tar}__scale", ds_name + "s",
-                    (step, 0), (1, scale.size), "s"))
-            self.staged_bytes += q.nbytes + scale.nbytes
-        else:
-            self.session.write(ds_name, np.ascontiguousarray(x),
-                               dtype=str(x.dtype))
-            with self._lock:
-                self._pending.append(LoadSubtar(
-                    tar, ds_name, (step,) + (0,) * x.ndim,
-                    (1,) + x.shape, "v"))
-            self.staged_bytes += x.nbytes
-        self.staged_arrays += 1
+        nbytes = int(getattr(arr, "nbytes", 0))
+        with obs.span("sink.stage", ds=ds_name, bytes=nbytes):
+            with obs.span("sink.d2h", ds=ds_name, bytes=nbytes):
+                x = np.asarray(arr)               # device_get for jax arrays
+            quantized = self.cfg.quantize == "int8" and x.dtype.kind == "f"
+            self._ensure_tar(tar, x.shape, str(x.dtype), quantized)
+            if quantized:
+                q, scale = quantize_int8_np(x, self.cfg.quant_block)
+                self.session.write(ds_name, q, dtype="int8")
+                self.session.write(ds_name + "s", scale, dtype="float32")
+                with self._lock:
+                    self._pending.append(LoadSubtar(
+                        tar, ds_name, (step, 0), (1, q.size), "v"))
+                    self._pending.append(LoadSubtar(
+                        f"{tar}__scale", ds_name + "s",
+                        (step, 0), (1, scale.size), "s"))
+                self.staged_bytes += q.nbytes + scale.nbytes
+            else:
+                self.session.write(ds_name, np.ascontiguousarray(x),
+                                   dtype=str(x.dtype))
+                with self._lock:
+                    self._pending.append(LoadSubtar(
+                        tar, ds_name, (step,) + (0,) * x.ndim,
+                        (1,) + x.shape, "v"))
+                self.staged_bytes += x.nbytes
+            self.staged_arrays += 1
 
     def stage_tree(self, prefix: str, tree: Any, step: int = 0) -> None:
         import jax
@@ -179,18 +183,21 @@ class InTransitSink:
         """Block until staged data is queryable in SAVIME (sync + drain +
         pending load_subtar DDL). The hot loop never calls this; analysis
         clients / checkpoint barriers do."""
-        self.session.sync(timeout)
-        self.session.drain(timeout)
-        with self._lock:
-            pending, self._pending = self._pending, []
-        seen = set()
-        for q in pending:
-            # replay-after-restore stages the same step twice: the dataset
-            # name is the idempotency token — run its DDL once
-            if q in seen:
-                continue
-            seen.add(q)
-            self.session.run_savime(q)
+        with obs.span("sink.flush") as sp:
+            self.session.sync(timeout)
+            self.session.drain(timeout)
+            with self._lock:
+                pending, self._pending = self._pending, []
+            seen = set()
+            for q in pending:
+                # replay-after-restore stages the same step twice: the
+                # dataset name is the idempotency token — run its DDL once
+                if q in seen:
+                    continue
+                seen.add(q)
+                with obs.span("sink.load_subtar", ds=q.dataset):
+                    self.session.run_savime(q)
+            sp.set(datasets=len(seen), pinned_bytes=self.session.held_bytes)
 
     def close(self) -> None:
         try:

@@ -29,6 +29,7 @@ from typing import Any, Callable, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.core.tars import TAR, Attribute, Dimension
 from repro.core import wire
 
@@ -149,10 +150,13 @@ class SavimeEngine:
         return "ok"
 
     def _q_select(self, tar: str, attr: str, lo: str = "", hi: str = ""):
-        t = self._tar(tar)
-        lo_t = tuple(int(x) for x in lo.split(",")) if lo else None
-        hi_t = tuple(int(x) for x in hi.split(",")) if hi else None
-        return t.select(attr, lo_t, hi_t)
+        with obs.span("savime.select", tar=tar) as sp:
+            t = self._tar(tar)
+            lo_t = tuple(int(x) for x in lo.split(",")) if lo else None
+            hi_t = tuple(int(x) for x in hi.split(",")) if hi else None
+            res = t.select(attr, lo_t, hi_t)
+            sp.set(bytes=int(getattr(res, "nbytes", 0)))
+            return res
 
     def _q_aggregate(self, tar: str, attr: str, op: str,
                      lo: str = "", hi: str = "") -> float:

@@ -43,6 +43,7 @@ import zlib
 from typing import Optional
 
 from repro import codec as codec_mod
+from repro import obs
 from repro.core import wire
 from repro.core.pagestore import PageStore, PageStoreFull
 from repro.core.queues import FCFSPool, TaskHandle
@@ -685,36 +686,39 @@ class StagingServer:
         """Dataset fully received (block-path sync or last stripe): account
         it, decode it if an egress codec applies at ingest, and queue the
         staging→SAVIME forward."""
-        ds.received_at = time.perf_counter()
-        ds.finished = True    # universal: the reaper must skip forwards
-        if ds.epoch:
-            with self._ds_lock:
-                first = (ds.name, ds.epoch) not in self._acked
-                if first:
-                    self._acked[(ds.name, ds.epoch)] = True
-                    while len(self._acked) > _ACKED_CAP:
-                        self._acked.popitem(last=False)
-            if not first:
-                # a replayed transfer raced the original's completion —
-                # both finished. Keep the copy already forwarding; free
-                # this one without double-counting it.
-                self.stats["replay_dups"] += 1
+        with obs.span("staging.ingest", ds=ds.name, bytes=ds.nbytes,
+                      decoded=bool(ds.codec and ds.decode_at == "staging")):
+            ds.received_at = time.perf_counter()
+            ds.finished = True    # universal: the reaper must skip forwards
+            if ds.epoch:
                 with self._ds_lock:
-                    self._datasets.pop(ds.file_id, None)
-                self._free_dataset(ds)
+                    first = (ds.name, ds.epoch) not in self._acked
+                    if first:
+                        self._acked[(ds.name, ds.epoch)] = True
+                        while len(self._acked) > _ACKED_CAP:
+                            self._acked.popitem(last=False)
+                if not first:
+                    # a replayed transfer raced the original's completion —
+                    # both finished. Keep the copy already forwarding; free
+                    # this one without double-counting it.
+                    self.stats["replay_dups"] += 1
+                    with self._ds_lock:
+                        self._datasets.pop(ds.file_id, None)
+                    self._free_dataset(ds)
+                    return
+            ds.region.deregister_all()   # paper: undo registration after sync
+            if ds.region.paged:
+                # fully received: pages become spillable / dedup-able
+                ds.region.seal()
+            self.stats["datasets"] += 1
+            self.stats["bytes_in"] += ds.nbytes          # wire (coded) bytes
+            self.stats["raw_bytes_in"] += (ds.raw_size if ds.codec
+                                           else ds.nbytes)
+            if ds.codec and ds.decode_at == "staging":
+                self._decode_ingest(ds)   # forwards (or parks) from inside
                 return
-        ds.region.deregister_all()   # paper: undo registration after sync
-        if ds.region.paged:
-            # fully received: pages become spillable / dedup-able
-            ds.region.seal()
-        self.stats["datasets"] += 1
-        self.stats["bytes_in"] += ds.nbytes          # wire (coded) bytes
-        self.stats["raw_bytes_in"] += ds.raw_size if ds.codec else ds.nbytes
-        if ds.codec and ds.decode_at == "staging":
-            self._decode_ingest(ds)   # forwards (or parks) from inside
-            return
-        self._send_pool.submit(self._send_to_savime, ds,
-                               name=f"send-{ds.name}")
+            self._send_pool.submit(self._send_to_savime, ds,
+                                   name=f"send-{ds.name}")
 
     # -- egress-codec decode (DESIGN.md §13) ------------------------------
     def _decoder(self, name: str) -> codec_mod.Codec:  # holds: self._codec_mutex
@@ -1019,42 +1023,43 @@ class StagingServer:
 
     # -- background forward (FCFS pool) ---------------------------------
     def _send_to_savime(self, ds: _Dataset) -> None:
-        sent = ds.nbytes
-        try:
-            cli = self._savime()
-            if ds.codec and not ds.decoded:
-                # decode_at="query": the dataset was staged in wire form
-                # (coded pages dedup and spill as-is); decode lazily on
-                # the staging→SAVIME hop
-                with self._codec_mutex:
-                    raw = self._decoder(ds.codec).decode(
-                        self._region_bytes(ds), ds.cmeta, key=ds.name)
-                cli.load_dataset(ds.name, ds.dtype, raw)
-                sent = int(getattr(raw, "nbytes", None) or len(raw))
-            elif ds.region.paged:
-                # gather page views (spilled pages stream from disk
-                # without displacing hot frames); pin so the LRU cannot
-                # evict a page out from under the send
-                ds.region.pin()
-                try:
-                    cli.load_dataset_views(ds.name, ds.dtype,
-                                           ds.region.page_views(),
-                                           ds.nbytes)
-                finally:
-                    ds.region.unpin()
-            else:
-                cli.load_dataset_from_file(ds.name, ds.dtype, ds.region.fd,
-                                           ds.nbytes)
-        except OSError:
-            if self._stop.is_set():
-                return    # stop() already closed the regions mid-forward
-            raise
-        self.stats["bytes_to_savime"] += sent
-        with self._ds_lock:
-            self._datasets.pop(ds.file_id, None)
-        self._free_dataset(ds)  # release staging memory (paper §3.2)
-        if ds.in_memory:
-            self._push_credits()
+        with obs.span("staging.forward", ds=ds.name, bytes=ds.nbytes):
+            sent = ds.nbytes
+            try:
+                cli = self._savime()
+                if ds.codec and not ds.decoded:
+                    # decode_at="query": the dataset was staged in wire form
+                    # (coded pages dedup and spill as-is); decode lazily on
+                    # the staging→SAVIME hop
+                    with self._codec_mutex:
+                        raw = self._decoder(ds.codec).decode(
+                            self._region_bytes(ds), ds.cmeta, key=ds.name)
+                    cli.load_dataset(ds.name, ds.dtype, raw)
+                    sent = int(getattr(raw, "nbytes", None) or len(raw))
+                elif ds.region.paged:
+                    # gather page views (spilled pages stream from disk
+                    # without displacing hot frames); pin so the LRU cannot
+                    # evict a page out from under the send
+                    ds.region.pin()
+                    try:
+                        cli.load_dataset_views(ds.name, ds.dtype,
+                                               ds.region.page_views(),
+                                               ds.nbytes)
+                    finally:
+                        ds.region.unpin()
+                else:
+                    cli.load_dataset_from_file(ds.name, ds.dtype, ds.region.fd,
+                                               ds.nbytes)
+            except OSError:
+                if self._stop.is_set():
+                    return    # stop() already closed the regions mid-forward
+                raise
+            self.stats["bytes_to_savime"] += sent
+            with self._ds_lock:
+                self._datasets.pop(ds.file_id, None)
+            self._free_dataset(ds)  # release staging memory (paper §3.2)
+            if ds.in_memory:
+                self._push_credits()
 
     def _push_credits(self) -> None:
         """Proactively raise windows on bin1 data connections after a

@@ -80,13 +80,14 @@ def cache_insert(cache: dict, k_new: jax.Array, v_new: jax.Array,
     Cache k/v are stored flat (B,T,Hkv*D)."""
     B = k_new.shape[0]
     T = cache["k"].shape[1]
-    b = jnp.arange(B)
-    slot = pos % T
-    return {
-        "k": cache["k"].at[b, slot].set(k_new.reshape(B, -1)),
-        "v": cache["v"].at[b, slot].set(v_new.reshape(B, -1)),
-        "pos": cache["pos"].at[b, slot].set(pos),
-    }
+    with jax.named_scope("kv_update"):
+        b = jnp.arange(B)
+        slot = pos % T
+        return {
+            "k": cache["k"].at[b, slot].set(k_new.reshape(B, -1)),
+            "v": cache["v"].at[b, slot].set(v_new.reshape(B, -1)),
+            "pos": cache["pos"].at[b, slot].set(pos),
+        }
 
 
 def cache_from_prefill(k: jax.Array, v: jax.Array, positions: jax.Array,

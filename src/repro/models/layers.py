@@ -166,8 +166,15 @@ def prefer_dtype(dt):
     return dt if _LOWP_COLLECTIVES else None
 
 
+def cast_weight(w: jax.Array, dtype) -> jax.Array:
+    """A stored weight in the compute dtype; the cast is named
+    ``weight_cast`` in the compiled program's op metadata."""
+    with jax.named_scope("weight_cast"):
+        return w.astype(dtype)
+
+
 def dense(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None) -> jax.Array:
-    y = jnp.einsum("...m,mn->...n", x, w.astype(x.dtype),
+    y = jnp.einsum("...m,mn->...n", x, cast_weight(w, x.dtype),
                    preferred_element_type=prefer_dtype(x.dtype))
     if b is not None:
         y = y + b.astype(x.dtype)
@@ -233,7 +240,7 @@ def embed_specs(vocab: int, d_model: int, tie: bool, pdt) -> dict[str, ParamSpec
 
 
 def embed(params: dict, tokens: jax.Array, scale: bool, dtype) -> jax.Array:
-    x = params["table"].astype(dtype)[tokens]
+    x = cast_weight(params["table"], dtype)[tokens]
     if scale:
         x = x * jnp.asarray(np.sqrt(params["table"].shape[1]), dtype)
     return x
@@ -241,4 +248,4 @@ def embed(params: dict, tokens: jax.Array, scale: bool, dtype) -> jax.Array:
 
 def unembed(params: dict, x: jax.Array, tie: bool) -> jax.Array:
     w = params["table"].T if tie else params["head"]
-    return jnp.einsum("...m,mv->...v", x, w.astype(x.dtype))
+    return jnp.einsum("...m,mv->...v", x, cast_weight(w, x.dtype))

@@ -111,11 +111,12 @@ def apply_block(kind: str, p: dict, x: jax.Array, aux: dict, *, cfg,
     new_cache = None
     if kind in ("dense", "global", "local", "moe"):
         h = rms_norm(x, p["ln1"], eps, plus)
-        a_out, new_cache = attn_lib.attention(
-            p["attn"], h, cfg=cfg, rules=rules,
-            kind="global" if kind == "moe" else kind,
-            positions=positions, cache=cache, return_cache=return_cache,
-            cache_len=cache_len)
+        with jax.named_scope("attn"):
+            a_out, new_cache = attn_lib.attention(
+                p["attn"], h, cfg=cfg, rules=rules,
+                kind="global" if kind == "moe" else kind,
+                positions=positions, cache=cache,
+                return_cache=return_cache, cache_len=cache_len)
         a_out = name(a_out, "attn_out")
         if cfg.post_norms:
             a_out = rms_norm(a_out, p["ln1_post"], eps, plus)
@@ -125,7 +126,8 @@ def apply_block(kind: str, p: dict, x: jax.Array, aux: dict, *, cfg,
             f_out, moe_aux = moe_lib.moe_block(p["moe"], h2, cfg=cfg, rules=rules)
             aux = {k: aux[k] + moe_aux.get(k, 0.0) for k in aux}
         else:
-            f_out = mlp(p["mlp"], h2, cfg.mlp_act, rules)
+            with jax.named_scope("mlp"):
+                f_out = mlp(p["mlp"], h2, cfg.mlp_act, rules)
         f_out = name(f_out, "ffn_out")
         if cfg.post_norms:
             f_out = rms_norm(f_out, p["ln2_post"], eps, plus)
@@ -233,7 +235,8 @@ def apply_transformer(params: dict, tokens: jax.Array, *, cfg, rules: dict,
     """Returns (hidden (B,S_total,M), aux, new_cache). Logits are computed by
     the caller (chunked xent for train; last-token unembed for prefill)."""
     cdt = jnp.dtype(cfg.compute_dtype)
-    x = embed(params["embed"], tokens, cfg.scale_embeddings, cdt)
+    with jax.named_scope("embed"):
+        x = embed(params["embed"], tokens, cfg.scale_embeddings, cdt)
     if prefix_embed is not None:
         x = jnp.concatenate([prefix_embed.astype(cdt), x], axis=1)
     B, S, _ = x.shape
@@ -249,7 +252,8 @@ def apply_transformer(params: dict, tokens: jax.Array, *, cfg, rules: dict,
 
 def logits_from_hidden(params: dict, hidden: jax.Array, cfg,
                        rules: Optional[dict] = None) -> jax.Array:
-    lg = unembed(params["embed"], hidden, cfg.tie_embeddings)
+    with jax.named_scope("head"):
+        lg = unembed(params["embed"], hidden, cfg.tie_embeddings)
     if rules is not None:
         lg = constrain(lg, rules, "batch", None, "vocab")
     return softcap(lg, cfg.logit_softcap)

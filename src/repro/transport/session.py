@@ -19,7 +19,8 @@ session owns:
     never run arbitrarily far ahead of the network);
   * futures — every ``write`` returns a :class:`DatasetFuture`;
   * metrics — :class:`~repro.transport.base.TransferStats` with per-phase
-    timings, plus optional ``on_event`` hooks for live instrumentation.
+    timings; ``write``, ``sync`` and ``drain`` are :mod:`repro.obs` spans
+    (``session.write``, ``session.sync``, ``session.drain``).
 """
 from __future__ import annotations
 
@@ -31,6 +32,7 @@ from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro import obs
 from repro.transport.base import (Transport, TransportConfig, TransferStats,
                                   create)
 
@@ -124,15 +126,13 @@ class TransferSession:
 
     def __init__(self, transport: "str | Transport",
                  cfg: Optional[TransportConfig] = None, *,
-                 label: Optional[str] = None,
-                 on_event: Optional[Callable[[dict], None]] = None):
+                 label: Optional[str] = None):
         if isinstance(transport, Transport):
             self.transport = transport
         else:
             self.transport = create(transport, cfg or TransportConfig())
         self.cfg = self.transport.cfg
         self.stats = TransferStats(engine=label or self.transport.name)
-        self.hooks: list[Callable[[dict], None]] = [on_event] if on_event else []
         self._opened = False
         self._closed = False
         self._t0: Optional[float] = None          # first-write clock
@@ -168,7 +168,6 @@ class TransferSession:
             self._replay_worker = threading.Thread(
                 target=self._replay_loop, name="session-replay", daemon=True)
             self._replay_worker.start()
-        self._emit("open")
         return self
 
     def __enter__(self) -> "TransferSession":
@@ -202,7 +201,6 @@ class TransferSession:
             self.stats.close_s = time.perf_counter() - t
             if self._t0 is not None and self.stats.end_to_end_s == 0.0:
                 self.stats.end_to_end_s = t - self._t0
-            self._emit("close")
 
     # -- data plane -----------------------------------------------------
     def write(self, name: str, buf, dtype: Optional[str] = None,
@@ -220,55 +218,57 @@ class TransferSession:
             arr = arr.reshape(-1).view(np.uint8)[:nbytes]
         dtype = dtype or str(arr.dtype)
         size = arr.nbytes
-        limit = self.cfg.max_inflight_bytes
-        t_wait = time.perf_counter()
-        with self._cond:
-            while limit and self._inflight > 0 and \
-                    self._inflight + size > limit:
-                self._cond.wait(0.5)
-            self._inflight += size
-            self.stats.peak_inflight_bytes = max(
-                self.stats.peak_inflight_bytes, self._inflight)
-        self.stats.write_wait_s += time.perf_counter() - t_wait
-        if self._t0 is None:
-            self._t0 = time.perf_counter()
-        epoch = None
-        if self._journal_on:
+        with obs.span("session.write", ds=name, bytes=size) as sp:
+            limit = self.cfg.max_inflight_bytes
+            t_wait = time.perf_counter()
             with self._cond:
-                self._epoch_seq += 1
-                epoch = f"{self._epoch_tag}-{self._epoch_seq}"
-        try:
-            if epoch is not None:
-                entry = _Journaled(
-                    name, dtype, arr, epoch, _ReplayHandle(name),
-                    deadline=(time.monotonic() + self.cfg.deadline_s
-                              if self.cfg.deadline_s else None))
+                while limit and self._inflight > 0 and \
+                        self._inflight + size > limit:
+                    self._cond.wait(0.5)
+                self._inflight += size
+                self.stats.peak_inflight_bytes = max(
+                    self.stats.peak_inflight_bytes, self._inflight)
+            wait_s = time.perf_counter() - t_wait
+            self.stats.write_wait_s += wait_s
+            sp.set(wait_s=wait_s)
+            if self._t0 is None:
+                self._t0 = time.perf_counter()
+            epoch = None
+            if self._journal_on:
                 with self._cond:
-                    self._journal[epoch] = entry
-                inner = self.transport.write_epoch(name, dtype, arr, epoch)
-                inner.add_done_callback(self._journal_chain(entry))
-                handle = entry.outer
-            else:
-                handle = self.transport.write(name, dtype, arr)
-        except BaseException:
-            # striped transports can fail synchronously (stripe_open is a
-            # control RTT); the reserved inflight bytes must be returned
-            # or later writes block against a phantom reservation
-            with self._cond:
+                    self._epoch_seq += 1
+                    epoch = f"{self._epoch_tag}-{self._epoch_seq}"
+            try:
                 if epoch is not None:
-                    self._journal.pop(epoch, None)
-                self._inflight -= size
-                self._cond.notify_all()
-            raise
-        fut = DatasetFuture(name, size, handle)
-        with self._cond:
-            self._pinned[id(fut)] = arr           # pin until completion
-        handle.add_done_callback(lambda _h: self._release(fut))
-        self._unsynced = self._undrained = True
-        self.stats.nbytes += size
-        self.stats.n_datasets += 1
-        self._emit("write", name=name, nbytes=size)
-        return fut
+                    entry = _Journaled(
+                        name, dtype, arr, epoch, _ReplayHandle(name),
+                        deadline=(time.monotonic() + self.cfg.deadline_s
+                                  if self.cfg.deadline_s else None))
+                    with self._cond:
+                        self._journal[epoch] = entry
+                    inner = self.transport.write_epoch(name, dtype, arr, epoch)
+                    inner.add_done_callback(self._journal_chain(entry))
+                    handle = entry.outer
+                else:
+                    handle = self.transport.write(name, dtype, arr)
+            except BaseException:
+                # striped transports can fail synchronously (stripe_open is a
+                # control RTT); the reserved inflight bytes must be returned
+                # or later writes block against a phantom reservation
+                with self._cond:
+                    if epoch is not None:
+                        self._journal.pop(epoch, None)
+                    self._inflight -= size
+                    self._cond.notify_all()
+                raise
+            fut = DatasetFuture(name, size, handle)
+            with self._cond:
+                self._pinned[id(fut)] = arr           # pin until completion
+            handle.add_done_callback(lambda _h: self._release(fut))
+            self._unsynced = self._undrained = True
+            self.stats.nbytes += size
+            self.stats.n_datasets += 1
+            return fut
 
     def write_all(self, names: Sequence[str], buffers: Sequence) \
             -> list[DatasetFuture]:
@@ -324,8 +324,6 @@ class TransferSession:
             delay = min(2.0, 0.05 * (1 << min(entry.attempts, 6)))
             if self._close_evt.wait(delay):
                 return
-            self._emit("replay", name=entry.name, epoch=epoch,
-                       attempt=entry.attempts)
             try:
                 inner = self.transport.write_epoch(
                     entry.name, entry.dtype, entry.arr, epoch, replay=True)
@@ -351,40 +349,41 @@ class TransferSession:
         """Block until all written buffers reached staging — including
         journaled writes still being replayed after a reconnect."""
         self._check_live()
-        deadline = time.monotonic() + timeout if timeout else None
-        self.transport.sync(timeout)
-        if self._journal_on:
-            # a replaying write is out of the transport's queues (its
-            # failed attempt completed there) but not yet durable — the
-            # sync contract covers it too
-            with self._cond:
-                while self._journal:
-                    remaining = None
-                    if deadline is not None:
-                        remaining = deadline - time.monotonic()
-                        if remaining <= 0:
-                            raise TimeoutError(
-                                f"{len(self._journal)} journaled writes "
-                                "still replaying")
-                    self._cond.wait(min(remaining, 0.25)
-                                    if remaining else 0.25)
-        # only the sync that follows new writes defines the phase timing —
-        # the redundant sync on clean __exit__ must not inflate it
-        if self._t0 is not None and self._unsynced:
-            self.stats.to_staging_s = time.perf_counter() - self._t0
-        self._unsynced = False
-        self._collect_channel_stats()
-        self._collect_codec_stats()
-        self._emit("sync")
+        with obs.span("session.sync"):
+            deadline = time.monotonic() + timeout if timeout else None
+            self.transport.sync(timeout)
+            if self._journal_on:
+                # a replaying write is out of the transport's queues (its
+                # failed attempt completed there) but not yet durable —
+                # the sync contract covers it too
+                with self._cond:
+                    while self._journal:
+                        remaining = None
+                        if deadline is not None:
+                            remaining = deadline - time.monotonic()
+                            if remaining <= 0:
+                                raise TimeoutError(
+                                    f"{len(self._journal)} journaled "
+                                    "writes still replaying")
+                        self._cond.wait(min(remaining, 0.25)
+                                        if remaining else 0.25)
+            # only the sync that follows new writes defines the phase
+            # timing — the redundant sync on clean __exit__ must not
+            # inflate it
+            if self._t0 is not None and self._unsynced:
+                self.stats.to_staging_s = time.perf_counter() - self._t0
+            self._unsynced = False
+            self._collect_channel_stats()
+            self._collect_codec_stats()
 
     def drain(self, timeout: Optional[float] = None) -> None:
         """Block until staged data is queryable at the endpoint."""
         self._check_live()
-        self.transport.drain(timeout)
+        with obs.span("session.drain"):
+            self.transport.drain(timeout)
         if self._t0 is not None and self._undrained:
             self.stats.end_to_end_s = time.perf_counter() - self._t0
         self._undrained = False
-        self._emit("drain")
 
     # -- control plane --------------------------------------------------
     def run_savime(self, q):
@@ -414,18 +413,15 @@ class TransferSession:
         with self._cond:
             return self._inflight
 
-    def add_metrics_hook(self, fn: Callable[[dict], None]) -> None:
-        self.hooks.append(fn)
-
-    def _emit(self, event: str, **kw) -> None:
-        if not self.hooks:
-            return
-        payload = {"event": event, "engine": self.stats.engine, **kw}
-        for fn in self.hooks:
-            try:
-                fn(payload)
-            except Exception:  # noqa: BLE001 — hooks must not break egress
-                pass
+    @property
+    def held_bytes(self) -> int:
+        """Bytes of the buffers the session still references: pinned
+        until their transfer completes, or journaled until acked."""
+        with self._cond:
+            held = {id(a): a.nbytes for a in self._pinned.values()}
+            held.update((id(e.arr), e.arr.nbytes)
+                        for e in self._journal.values())
+        return sum(held.values())
 
     def _collect_channel_stats(self) -> None:
         """Snapshot per-channel byte/latency breakdowns into the stats

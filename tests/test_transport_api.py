@@ -91,15 +91,32 @@ def test_session_context_manager_semantics(staging):
         sess.write("y", np.ones(8))
 
 
-def test_session_metrics_hooks(staging):
-    events = []
+def test_session_metrics_hooks(staging, tmp_path):
+    """The session's instrumentation is its spans: one write, sync and
+    drain under an active profiler session leave their records, and the
+    same calls with the profiler off leave none."""
+    import jax
+    from repro import obs
     cfg = TransportConfig(staging_addr=staging.addr)
-    with TransferSession("rdma_staged", cfg, on_event=events.append) as sess:
-        sess.write("m", np.ones(64))
+    with TransferSession("rdma_staged", cfg) as sess:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            sess.write("m", np.ones(64))
+            sess.sync()
+            sess.drain()
+        finally:
+            jax.profiler.stop_trace()
+        traced = obs.spans()
+        sess.write("n", np.ones(64))
         sess.sync()
-    kinds = [e["event"] for e in events]
-    for expected in ("open", "write", "sync", "drain", "close"):
-        assert expected in kinds
+        sess.drain()
+        assert obs.spans() == traced
+    names = [s.name for s in traced]
+    for expected in ("session.write", "session.sync", "session.drain"):
+        assert names.count(expected) == 1
+    write, = (s for s in traced if s.name == "session.write")
+    assert write.attrs["ds"] == "m" and write.attrs["bytes"] == 64 * 8
+    assert write.attrs["wait_s"] >= 0
 
 
 def test_backpressure_bounds_inflight_bytes(staging):
