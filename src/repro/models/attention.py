@@ -215,12 +215,15 @@ def decode_attention_xla(q, k_cache, v_cache, *, pos, cache_positions, scale,
 
 def attention(params: dict, x: jax.Array, *, cfg, rules: dict, kind: str,
               positions: jax.Array, cache: Optional[dict] = None,
-              return_cache: bool = False, cache_len: int = 0):
+              return_cache: bool = False, cache_len: int = 0,
+              layer: Optional[jax.Array] = None):
     """kind: dense|global|local. x: (B,S,M). positions: (B,S) absolute.
 
     Modes:
       * train/prefill: cache is None; returns (y, new_cache|None)
-      * decode:        cache is dict;  returns (y, updated_cache)
+      * decode:        cache is dict;  returns (y, updated_cache). With
+        `layer`, cache is the scan's stacked cache, this layer's row is
+        updated in place and the whole stack returned.
     """
     B, S, M = x.shape
     Hq, Hkv, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -276,16 +279,16 @@ def attention(params: dict, x: jax.Array, *, cfg, rules: dict, kind: str,
         k_att, v_att = k, v
 
     if decode:  # one-token decode against the cache
-        from repro.models.kvcache import cache_insert  # local import: no cycle
-        cache = cache_insert(cache, k, v, positions[:, 0], window=window)
-        T = cache["k"].shape[1]
-        kc = cache["k"].reshape(B, T, Hkv, D)
-        vc = cache["v"].reshape(B, T, Hkv, D)
+        from repro.models.kvcache import cache_insert  # local: no cycle
+        new_cache, own = cache_insert(cache, k, v, positions[:, 0],
+                                      layer=layer)
+        T = own["k"].shape[1]
+        kc = own["k"].reshape(B, T, Hkv, D)
+        vc = own["v"].reshape(B, T, Hkv, D)
         o = decode_attention_xla(
             qg, kc, vc, pos=positions[:, 0],
-            cache_positions=cache["pos"], scale=scale, cap=cfg.attn_softcap,
+            cache_positions=own["pos"], scale=scale, cap=cfg.attn_softcap,
             window=window)
-        new_cache = cache
     else:  # train / prefill
         if kind == "local":
             o = local_attention_xla(qg, k_att, v_att, window=cfg.attn_window,

@@ -5,6 +5,14 @@ ring buffer (slot = pos % window) with the same insert path as global layers.
 Global-layer caches are sequence-shardable over the `data` mesh axis for
 long-context decode (SP decode; see DESIGN.md §4) via the `kv_seq` logical
 axis.
+
+Layers under the layer scan keep their caches stacked on a leading `layers`
+axis. Prefill builds them as the scan's outputs. Decode carries the stacked
+caches through the scan and updates them in place: each layer reads its row
+with a dynamic index (`layer_of`), an attention layer scatters its new token
+into `[layer, b, pos % T]` (`cache_insert`), and a recurrent layer replaces
+its row whole (`state_replace`). Each op takes `layer=None` for the cache of
+one unstacked layer.
 """
 from __future__ import annotations
 
@@ -74,20 +82,52 @@ def layer_cache_specs(cfg, kind: str, B: int, T: int) -> Optional[dict]:
 # ---------------------------------------------------------------------------
 
 
+def layer_of(cache: Optional[dict], layer: Optional[jax.Array]):
+    """The cache of one layer: row `layer` of a stacked cache, or `cache`
+    itself when `layer` is None."""
+    if layer is None:
+        return cache
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        cache)
+
+
 def cache_insert(cache: dict, k_new: jax.Array, v_new: jax.Array,
-                 pos: jax.Array, window: int = 0) -> dict:
+                 pos: jax.Array, layer: Optional[jax.Array] = None
+                 ) -> tuple[dict, dict]:
     """Insert one token per sequence. k_new/v_new: (B,1,Hkv,D); pos: (B,).
-    Cache k/v are stored flat (B,T,Hkv*D)."""
+    Cache k/v are stored flat (B,T,Hkv*D), or (L,B,T,Hkv*D) stacked, in which
+    case the token goes to row `layer` in place: one scatter of B tokens.
+
+    Returns (cache, the layer's own cache), both holding the token. For a
+    stacked cache the layer's own is its row read before the insert, with
+    the token scattered into it too: the attention reads that copy, since
+    reading the row back out of the updated stack costs 4 ms more of a
+    musicgen-medium decode step at 16 x 750 tokens on one TPU v5e."""
     B = k_new.shape[0]
-    T = cache["k"].shape[1]
+    T = cache["k"].shape[-2]
+    new = {"k": k_new.reshape(B, -1), "v": v_new.reshape(B, -1), "pos": pos}
+
+    def put(c, idx):
+        return {name: c[name].at[idx].set(new[name]) for name in new}
+
     with jax.named_scope("kv_update"):
-        b = jnp.arange(B)
-        slot = pos % T
-        return {
-            "k": cache["k"].at[b, slot].set(k_new.reshape(B, -1)),
-            "v": cache["v"].at[b, slot].set(v_new.reshape(B, -1)),
-            "pos": cache["pos"].at[b, slot].set(pos),
-        }
+        rows = (jnp.arange(B), pos % T)
+        if layer is None:
+            own = put(cache, rows)
+            return own, own
+        return put(cache, (layer,) + rows), put(layer_of(cache, layer), rows)
+
+
+def state_replace(cache: Optional[dict], new: Optional[dict],
+                  layer: Optional[jax.Array] = None) -> Optional[dict]:
+    """Recurrent state (conv, h) is replaced whole every step: `new` is the
+    layer's cache, or is written into row `layer` of the stacked `cache`."""
+    if layer is None:
+        return new
+    return jax.tree.map(
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
+        cache, new)
 
 
 def cache_from_prefill(k: jax.Array, v: jax.Array, positions: jax.Array,

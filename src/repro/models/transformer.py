@@ -17,8 +17,9 @@ import jax.numpy as jnp
 from repro.models import attention as attn_lib
 from repro.models import kvcache, moe as moe_lib, rglru as rglru_lib, ssm as ssm_lib
 from repro.models.layers import (
-    ParamSpec, constrain, embed, embed_specs, mlp, mlp_specs, rms_norm,
-    rms_norm_spec, softcap, stack_specs, unembed,
+    ParamSpec, constrain, embed, embed_specs, mlp, mlp_specs,
+    param_logical_axes, rms_norm, rms_norm_spec, softcap, stack_specs,
+    unembed,
 )
 
 AUX0 = {"moe_lb": 0.0, "moe_z": 0.0}
@@ -105,7 +106,9 @@ def cache_specs(cfg, B: int, T: int) -> dict:
 def apply_block(kind: str, p: dict, x: jax.Array, aux: dict, *, cfg,
                 rules: dict, positions: jax.Array,
                 cache: Optional[dict], return_cache: bool,
-                cache_len: int = 0):
+                cache_len: int = 0, layer: Optional[jax.Array] = None):
+    """One layer. With `layer`, `cache` is the scan's stacked cache of this
+    kind and comes back with row `layer` updated (kvcache)."""
     from jax.ad_checkpoint import checkpoint_name as name
     eps, plus = cfg.norm_eps, cfg.scale_embeddings
     new_cache = None
@@ -116,7 +119,7 @@ def apply_block(kind: str, p: dict, x: jax.Array, aux: dict, *, cfg,
                 p["attn"], h, cfg=cfg, rules=rules,
                 kind="global" if kind == "moe" else kind,
                 positions=positions, cache=cache,
-                return_cache=return_cache, cache_len=cache_len)
+                return_cache=return_cache, cache_len=cache_len, layer=layer)
         a_out = name(a_out, "attn_out")
         if cfg.post_norms:
             a_out = rms_norm(a_out, p["ln1_post"], eps, plus)
@@ -135,14 +138,16 @@ def apply_block(kind: str, p: dict, x: jax.Array, aux: dict, *, cfg,
     elif kind == "mamba":
         h = rms_norm(x, p["ln1"], eps, plus)
         out, new_cache = ssm_lib.mamba_block(
-            p["mamba"], h, cfg=cfg, rules=rules, cache=cache,
-            return_cache=return_cache)
+            p["mamba"], h, cfg=cfg, rules=rules,
+            cache=kvcache.layer_of(cache, layer), return_cache=return_cache)
+        new_cache = kvcache.state_replace(cache, new_cache, layer)
         x = x + name(out, "mixer_out")
     elif kind == "rglru":
         h = rms_norm(x, p["ln1"], eps, plus)
         out, new_cache = rglru_lib.rglru_block(
-            p["rglru"], h, cfg=cfg, rules=rules, cache=cache,
-            return_cache=return_cache)
+            p["rglru"], h, cfg=cfg, rules=rules,
+            cache=kvcache.layer_of(cache, layer), return_cache=return_cache)
+        new_cache = kvcache.state_replace(cache, new_cache, layer)
         x = x + name(out, "mixer_out")
         h2 = rms_norm(x, p["ln2"], eps, plus)
         x = x + name(mlp(p["mlp"], h2, cfg.mlp_act, rules), "ffn_out")
@@ -171,7 +176,13 @@ def _remat(cfg, fn):
 def apply_stack(params: dict, x: jax.Array, *, cfg, rules: dict,
                 positions: jax.Array, cache: Optional[dict] = None,
                 return_cache: bool = False, cache_len: int = 0):
-    """Runs all layers. Returns (x, aux, new_cache|None)."""
+    """Runs all layers. Returns (x, aux, new_cache|None).
+
+    Scanned layers' caches are stacked on a leading `layers` axis. Prefill
+    (`return_cache`, no input cache) builds them as the scan's outputs.
+    Decode (an input cache) carries them through the scan and updates each
+    layer's row in place (kvcache), so a step writes only its new tokens.
+    """
     pat = cfg.layer_pattern
     n_scan, n_rem = _plan(cfg)
     aux = dict(AUX0)
@@ -182,29 +193,51 @@ def apply_stack(params: dict, x: jax.Array, *, cfg, rules: dict,
         # remat at BLOCK granularity: the scan saves only the carry per
         # period; backward recomputes one block at a time (working set =
         # one layer, not one period)
-        def block_fn(kind, p, xc, auxc, c_in):
+        def block_fn(kind, p, xc, auxc, c_in, layer=None):
             return apply_block(
                 kind, p, xc, auxc, cfg=cfg, rules=rules,
                 positions=positions, cache=c_in, return_cache=return_cache,
-                cache_len=cache_len)
+                cache_len=cache_len, layer=layer)
 
-        def body(carry, xs):
-            xc, auxc = carry
-            p_period, c_period = xs if use_cache else (xs, None)
-            outs = {}
-            for i, kind in enumerate(pat):
-                key = _key(i, kind)
-                c_in = c_period[key] if use_cache else None
-                fn = _remat(cfg, functools.partial(block_fn, kind))
-                xc, auxc, nc = fn(p_period[key], xc, auxc, c_in)
-                if nc is not None:
-                    outs[key] = nc
-            return (xc, auxc), (outs if outs else 0.0)
+        if use_cache:
+            # pin the carried caches to their stacked layout so GSPMD does
+            # not reshard them at the loop boundary
+            axes = param_logical_axes(cache_specs(cfg, 1, 1)["scan"])
 
-        xs = (params["scan"], cache["scan"]) if use_cache else params["scan"]
-        (x, aux), ys = jax.lax.scan(body, (x, aux), xs)
-        if use_cache or return_cache:
-            new_cache["scan"] = ys
+            def pin(c):
+                return jax.tree.map(lambda ax, a: constrain(a, rules, *ax),
+                                    axes, c,
+                                    is_leaf=lambda t: isinstance(t, tuple))
+
+            def decode_body(carry, xs):
+                xc, auxc, cc = carry
+                p_period, layer = xs
+                cc = dict(cc)
+                for i, kind in enumerate(pat):
+                    key = _key(i, kind)
+                    fn = _remat(cfg, functools.partial(block_fn, kind))
+                    xc, auxc, cc[key] = fn(p_period[key], xc, auxc, cc[key],
+                                           layer)
+                return (xc, auxc, pin(cc)), None
+
+            (x, aux, new_cache["scan"]), _ = jax.lax.scan(
+                decode_body, (x, aux, pin(cache["scan"])),
+                (params["scan"], jnp.arange(n_scan)))
+        else:
+            def body(carry, p_period):
+                xc, auxc = carry
+                outs = {}
+                for i, kind in enumerate(pat):
+                    key = _key(i, kind)
+                    fn = _remat(cfg, functools.partial(block_fn, kind))
+                    xc, auxc, nc = fn(p_period[key], xc, auxc, None)
+                    if nc is not None:
+                        outs[key] = nc
+                return (xc, auxc), (outs if outs else 0.0)
+
+            (x, aux), ys = jax.lax.scan(body, (x, aux), params["scan"])
+            if return_cache:
+                new_cache["scan"] = ys
 
     for j in range(n_rem):
         kind = pat[j % len(pat)]

@@ -150,8 +150,11 @@ def check_seq_sharded_decode():
     """SP decode: seq-sharded KV cache == replicated-cache decode."""
     import dataclasses
     from repro.train.serve_step import ServeSetup
-    cfg = dataclasses.replace(get_config("gemma3-4b").smoke(),
-                              compute_dtype="float32")
+    smoke = get_config("gemma3-4b").smoke()
+    # two scanned periods (caches carried through the layer scan) and an
+    # unrolled remainder layer
+    cfg = dataclasses.replace(smoke, compute_dtype="float32",
+                              n_layers=2 * len(smoke.layer_pattern) + 1)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(11))
     B, S = 1, 64
@@ -169,9 +172,12 @@ def check_seq_sharded_decode():
     cs = jax.device_put(jax.tree.map(np.asarray, cache),
                         setup.cache_shardings(B, S + 8))
     with jax.set_mesh(mesh):
-        lg, _ = jax.jit(setup.decode_fn())(
+        lg, new_cs = setup.jitted_decode(B, S + 8)(
             ps, cs, {"tokens": toks[:, S:S + 1],
                      "pos": jnp.full((B,), S, jnp.int32)})
+    want = setup.cache_shardings(B, S + 8)
+    for got, w in zip(jax.tree.leaves(new_cs), jax.tree.leaves(want)):
+        assert got.sharding.is_equivalent_to(w, got.ndim), (got.sharding, w)
     rel = float(jnp.max(jnp.abs(lg - ref_lg)) /
                 (jnp.max(jnp.abs(ref_lg)) + 1e-9))
     assert rel < 1e-4, rel

@@ -1,6 +1,7 @@
 """Per-architecture smoke tests: reduced same-family config, one forward +
 train step on CPU, shape and NaN checks; decode-vs-prefill consistency."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -106,6 +107,69 @@ def test_multi_token_decode_matches_prefill():
     rel = float(jnp.max(jnp.abs(lg_full - lg_dec)) /
                 (jnp.max(jnp.abs(lg_full)) + 1e-9))
     assert rel < 2e-4, rel
+
+
+def _layers(tree, pattern):
+    """Per-layer list of a model's params or caches, in layer order: rows of
+    the scanned stacks, then the unrolled remainder."""
+    p = len(pattern)
+    scan, rem = tree["scan"], tree["rem"]
+    n_scan = jax.tree.leaves(scan)[0].shape[0] if scan else 0
+    out = [jax.tree.map(lambda a, s=s: a[s], scan[f"{i}:{kind}"])
+           for s in range(n_scan) for i, kind in enumerate(pattern)]
+    return out + [rem[f"{j}:{pattern[j % p]}"] for j in range(len(rem))]
+
+
+def _unrolled(tree, pattern):
+    """The same tree keyed as a model with scan_layers=False keys it."""
+    p = len(pattern)
+    rem = {f"{l}:{pattern[l % p]}": layer
+           for l, layer in enumerate(_layers(tree, pattern))}
+    return {**tree, "scan": {}, "rem": rem}
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_scanned_decode_matches_unrolled(arch):
+    """Decode with the stacked caches carried through the layer scan and
+    updated in place equals decode through the unrolled per-layer path:
+    same logits, same caches, leaf by leaf."""
+    base = get_config(arch).smoke()
+    pat = base.layer_pattern
+    # two scanned periods and one unrolled remainder layer
+    cfg = dataclasses.replace(base, compute_dtype="float32",
+                              n_layers=2 * len(pat) + 1)
+    if cfg.moe:  # capacity drops are train-time semantics; disable here
+        cfg = dataclasses.replace(
+            cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0))
+    scanned = Model(cfg)
+    unrolled = Model(dataclasses.replace(cfg, scan_layers=False))
+    params = scanned.init(jax.random.PRNGKey(7))
+    assert params["scan"], "the scanned model must scan"
+    n_new, P = 4, cfg.n_prefix
+    toks = jax.random.randint(jax.random.PRNGKey(8), (B, S + n_new), 0,
+                              cfg.vocab_size)
+    pre = {}
+    if P:
+        pre["prefix_embed"] = jax.random.normal(
+            jax.random.PRNGKey(9), (B, P, cfg.d_model), jnp.float32) * 0.02
+    _, cache = scanned.prefill(params, toks[:, :S], rules={},
+                               max_len=S + P + n_new, **pre)
+    u_params, u_cache = _unrolled(params, pat), _unrolled(cache, pat)
+    step = jax.jit(functools.partial(scanned.decode_step, rules={}))
+    u_step = jax.jit(functools.partial(unrolled.decode_step, rules={}))
+    for t in range(n_new):
+        tok = toks[:, S + t:S + t + 1]
+        pos = jnp.full((B,), S + P + t, jnp.int32)
+        lg, cache = step(params, tok, pos, cache)
+        u_lg, u_cache = u_step(u_params, tok, pos, u_cache)
+        np.testing.assert_array_equal(lg, u_lg, err_msg=f"{arch} step {t}")
+    layers, u_layers = _layers(cache, pat), _layers(u_cache, pat)
+    assert len(layers) == len(u_layers) == cfg.n_layers
+    for l, (c, u) in enumerate(zip(layers, u_layers)):
+        assert c.keys() == u.keys()
+        for name in c:
+            np.testing.assert_array_equal(c[name], u[name],
+                                          err_msg=f"{arch} layer {l} {name}")
 
 
 def test_loss_mask_respected():

@@ -1,17 +1,20 @@
-"""Ahead-of-time compiles of the main path's Pallas kernels for a described
-TPU v5e chip, at real sizes.
+"""Ahead-of-time compiles of the main path's Pallas kernels, and of the
+served decode step, for a described TPU v5e chip, at real sizes.
 
 Nothing runs: the TPU compiler (Mosaic) only has to accept each kernel,
-which interpret-mode tests cannot show (block alignment, VMEM budget).
+which interpret-mode tests cannot show (block alignment, VMEM budget); the
+decode step's compiled program is read for what it allocates and copies.
 The topology is described inside a fixture, so every test worker collects
 the same tests and only the worker given this file loads the TPU library.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 FIELD = (201, 501, 501)  # one step of the paper's velocity mesh, float32
 
@@ -70,3 +73,51 @@ def test_flash_attention_compiles_at_musicgen_width(one_chip):
                                           causal=True),
         q, q, q)
     assert "tpu_custom_call" in txt
+
+
+def test_decode_updates_stacked_kv_cache_in_place(topo):
+    """The served decode step at the musicgen cells' shapes (float32
+    parameters, bf16 compute, 16 requests, 750-token cache, donated) writes
+    its new tokens into the stacked caches in place: no copy and no
+    whole-slice write of a stacked cache, and under 3.0 GB of temporaries
+    (with the caches as the layer scan's outputs it takes 6.41 GB)."""
+    from repro.configs import get_config
+    from repro.models import Model
+    from repro.train import ServeSetup
+    B, T = 16, 750
+    cfg = get_config("musicgen-medium")
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    model = Model(cfg)
+    setup = ServeSetup(model, mesh, global_batch=B)
+
+    def described(tree, shardings):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            tree, shardings)
+
+    params = described(model.abstract_params(), setup.param_shardings())
+    cache = described(model.abstract_cache(B, T), setup.cache_shardings(B, T))
+    rep = NamedSharding(mesh, PartitionSpec())
+    batch = {"tokens": jax.ShapeDtypeStruct((B, 1), jnp.int32, sharding=rep),
+             "pos": jax.ShapeDtypeStruct((B,), jnp.int32, sharding=rep)}
+    with jax.set_mesh(mesh):
+        compiled = jax.jit(setup.decode_fn(), donate_argnums=(1,)).lower(
+            params, cache, batch).compile()
+
+    stacked = f"bf16[{cfg.n_layers},{B},{T},{cfg.n_kv_heads * cfg.head_dim}]"
+    op = re.compile(r"^\s*(?:ROOT )?%(\S+) = " + re.escape(stacked)
+                    + r"\S* ([a-z-]+)\(")
+    inserts, offenders = [], []
+    for line in compiled.as_text().splitlines():
+        m = op.match(line)
+        if m is None or m.group(2) not in ("copy", "dynamic-update-slice",
+                                           "fusion"):
+            continue
+        if m.group(2) == "fusion" and "/attn/kv_update/scatter" in line:
+            inserts.append(m.group(1))
+        else:
+            offenders.append(m.group(1))
+    assert not offenders, offenders
+    assert len(inserts) == 2, inserts  # one scatter each into K and V
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 3.0e9, temp
