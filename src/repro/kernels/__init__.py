@@ -5,3 +5,6 @@
 #   flash_attention — online-softmax prefill kernel, GQA via index_map,
 #                     causal block skip, window + softcap
 #   ssm_scan        — mamba1 selective scan, VMEM-resident state
+#   decode_matmul   — few-row matmul that reads a float32 weight (one layer
+#                     of a stack) once and casts its tiles in VMEM; the
+#                     decode path runs it (interpret mode off the TPU)

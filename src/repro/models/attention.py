@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import (
-    ParamSpec, cast_weight, constrain, dense, rms_norm, rope, softcap,
+    ParamSpec, constrain, matmul, prefer_dtype, rms_norm, rope, softcap,
 )
 
 NEG_INF = -2.0e38  # fp32-safe large negative (avoid nan from inf-inf)
@@ -232,9 +232,9 @@ def attention(params: dict, x: jax.Array, *, cfg, rules: dict, kind: str,
     window = cfg.attn_window if kind == "local" else 0
     theta = cfg.rope_theta if kind != "local" else min(cfg.rope_theta, 10_000.0)
 
-    q = jnp.einsum("bsm,mf->bsf", x, cast_weight(params["wq"], x.dtype))
-    k = jnp.einsum("bsm,mf->bsf", x, cast_weight(params["wk"], x.dtype))
-    v = jnp.einsum("bsm,mf->bsf", x, cast_weight(params["wv"], x.dtype))
+    q = matmul(x, params["wq"])
+    k = matmul(x, params["wk"])
+    v = matmul(x, params["wv"])
     if cfg.qkv_bias:
         q = q + params["bq"].astype(x.dtype)
         k = k + params["bk"].astype(x.dtype)
@@ -312,7 +312,5 @@ def attention(params: dict, x: jax.Array, *, cfg, rules: dict, kind: str,
 
     o = o.reshape(B, S, Hq * D)
     o = constrain(o, rules, "batch", None, "qkv")
-    from repro.models.layers import prefer_dtype
-    y = jnp.einsum("bsf,fm->bsm", o, cast_weight(params["wo"], x.dtype),
-                   preferred_element_type=prefer_dtype(x.dtype))
+    y = matmul(o, params["wo"], prefer_dtype(x.dtype))
     return constrain(y, rules, "batch", None, None), new_cache

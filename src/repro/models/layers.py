@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.decode_matmul import ops as decode_matmul_ops
+
 PyTree = Any
 
 # ---------------------------------------------------------------------------
@@ -173,9 +175,78 @@ def cast_weight(w: jax.Array, dtype) -> jax.Array:
         return w.astype(dtype)
 
 
-def dense(x: jax.Array, w: jax.Array, b: Optional[jax.Array] = None) -> jax.Array:
-    y = jnp.einsum("...m,mn->...n", x, cast_weight(w, x.dtype),
-                   preferred_element_type=prefer_dtype(x.dtype))
+#: most activation rows for which a decode matmul reads a weight stored in
+#: another dtype in place (the decode_matmul kernel) instead of casting it
+#: first: up to here the product is bound by the weight's bytes
+DECODE_ROWS = 128
+
+#: sub-blocks whose matrices decode reads through `matmul`
+_DECODE_MATMUL_BLOCKS = ("attn", "mlp")
+
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=("stack", "layer"), meta_fields=("name",))
+@dataclasses.dataclass(frozen=True)
+class LayerWeight:
+    """A decode step's weight matrix, left unsliced: row `layer` of the
+    stacked `stack` (L, K, N), or `stack` itself (K, N) when `layer` is
+    None. `name` names the kernel that reads it in the device trace."""
+    stack: jax.Array
+    layer: Optional[jax.Array]
+    name: str
+
+    def row(self) -> jax.Array:
+        if self.layer is None:
+            return self.stack
+        return jax.lax.dynamic_index_in_dim(self.stack, self.layer,
+                                            keepdims=False)
+
+
+def decode_params(block: dict, layer: Optional[jax.Array] = None) -> dict:
+    """One layer's parameters for a decode step, from a block's parameters
+    (stacked on a leading layers axis when `layer` is given). Attention and
+    MLP matrices stay whole as `LayerWeight`s, so `matmul` can read them in
+    place; every other leaf is sliced."""
+    def leaf(path, a):
+        keys = [getattr(k, "key", None) for k in path]
+        if (len(keys) >= 2 and keys[-2] in _DECODE_MATMUL_BLOCKS
+                and a.ndim == (2 if layer is None else 3)):
+            return LayerWeight(a, layer, f"{keys[-2]}_{keys[-1]}")
+        return a if layer is None else jax.lax.dynamic_index_in_dim(
+            a, layer, keepdims=False)
+    return jax.tree_util.tree_map_with_path(leaf, block)
+
+
+def _single_device() -> bool:
+    """Whether the program being traced runs on one device: by the mesh it
+    is traced under, or without one, by the devices the process has."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return jax.device_count() == 1 if mesh.empty else mesh.size == 1
+
+
+def matmul(x: jax.Array, w, preferred=None) -> jax.Array:
+    """x @ w in x's dtype. A `LayerWeight` stored in another dtype than x
+    (float32 weights, bf16 compute), read by at most `DECODE_ROWS` rows in
+    a one-device program, goes through the decode_matmul kernel: its tiles
+    are read from HBM as stored and cast in VMEM, so no cast copy of the
+    weight is written. Anything else is cast (`cast_weight`) and multiplied
+    by XLA. The kernel is a Mosaic call that GSPMD cannot partition, so a
+    program over several devices keeps XLA's path."""
+    if isinstance(w, LayerWeight):
+        rows = int(np.prod(x.shape[:-1]))
+        if (w.stack.dtype != x.dtype and rows <= DECODE_ROWS
+                and _single_device()):
+            y = decode_matmul_ops.decode_matmul(
+                x.reshape(rows, x.shape[-1]), w.stack, w.layer,
+                name=f"decode_matmul_{w.name}")
+            return y.reshape(*x.shape[:-1], y.shape[-1])
+        w = w.row()
+    return jnp.einsum("...m,mn->...n", x, cast_weight(w, x.dtype),
+                      preferred_element_type=preferred)
+
+
+def dense(x: jax.Array, w, b: Optional[jax.Array] = None) -> jax.Array:
+    y = matmul(x, w, prefer_dtype(x.dtype))
     if b is not None:
         y = y + b.astype(x.dtype)
     return y

@@ -17,7 +17,7 @@ import jax.numpy as jnp
 from repro.models import attention as attn_lib
 from repro.models import kvcache, moe as moe_lib, rglru as rglru_lib, ssm as ssm_lib
 from repro.models.layers import (
-    ParamSpec, constrain, embed, embed_specs, mlp, mlp_specs,
+    ParamSpec, constrain, decode_params, embed, embed_specs, mlp, mlp_specs,
     param_logical_axes, rms_norm, rms_norm_spec, softcap, stack_specs,
     unembed,
 )
@@ -181,7 +181,10 @@ def apply_stack(params: dict, x: jax.Array, *, cfg, rules: dict,
     Scanned layers' caches are stacked on a leading `layers` axis. Prefill
     (`return_cache`, no input cache) builds them as the scan's outputs.
     Decode (an input cache) carries them through the scan and updates each
-    layer's row in place (kvcache), so a step writes only its new tokens.
+    layer's row in place (kvcache), so a step writes only its new tokens;
+    its attention and MLP matrices reach the layer unsliced
+    (`decode_params`), so a weight stored wider than the compute dtype is
+    read once, in place, by the matmul that uses it.
     """
     pat = cfg.layer_pattern
     n_scan, n_rem = _plan(cfg)
@@ -209,20 +212,19 @@ def apply_stack(params: dict, x: jax.Array, *, cfg, rules: dict,
                                     axes, c,
                                     is_leaf=lambda t: isinstance(t, tuple))
 
-            def decode_body(carry, xs):
+            def decode_body(carry, layer):
                 xc, auxc, cc = carry
-                p_period, layer = xs
                 cc = dict(cc)
                 for i, kind in enumerate(pat):
                     key = _key(i, kind)
                     fn = _remat(cfg, functools.partial(block_fn, kind))
-                    xc, auxc, cc[key] = fn(p_period[key], xc, auxc, cc[key],
-                                           layer)
+                    xc, auxc, cc[key] = fn(
+                        decode_params(params["scan"][key], layer), xc, auxc,
+                        cc[key], layer)
                 return (xc, auxc, pin(cc)), None
 
             (x, aux, new_cache["scan"]), _ = jax.lax.scan(
-                decode_body, (x, aux, pin(cache["scan"])),
-                (params["scan"], jnp.arange(n_scan)))
+                decode_body, (x, aux, pin(cache["scan"])), jnp.arange(n_scan))
         else:
             def body(carry, p_period):
                 xc, auxc = carry
@@ -251,7 +253,9 @@ def apply_stack(params: dict, x: jax.Array, *, cfg, rules: dict,
                                return_cache=return_cache,
                                cache_len=cache_len)  # rematted below
 
-        xr, aux, nc = _remat(cfg, one)((x, aux), params["rem"][key])
+        p = params["rem"][key]
+        xr, aux, nc = _remat(cfg, one)(
+            (x, aux), decode_params(p) if use_cache else p)
         x = xr
         if nc is not None:
             new_cache["rem"][key] = nc

@@ -158,3 +158,58 @@ def test_ssm_scan_vs_ref(B, S, di, N, chunk, dtile, dtype):
     np.testing.assert_allclose(np.asarray(hp), np.asarray(h_ref), atol=tol)
     np.testing.assert_allclose(np.asarray(yx, np.float32),
                                np.asarray(y0, np.float32), atol=tol)
+
+
+# ---------------------------------------------------------------------------
+# decode_matmul
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("M,K,N,block_k,block_n", [
+    (16, 256, 384, 256, 384),      # one tile
+    (16, 512, 768, 128, 256),      # 4 x 3 tiles, all whole
+    (5, 200, 300, 200, 300),       # dims off the 128 grid, whole
+    (3, 700, 260, 128, 128),       # partial last K and N tiles
+    (1, 384, 640, 384, 256),       # one row, partial N tile
+])
+def test_decode_matmul_vs_ref(M, K, N, block_k, block_n):
+    """The float32 weight read in tiles and cast in VMEM gives the product
+    with the weight cast first, to the rounding of a reordered sum."""
+    from repro.kernels.decode_matmul import kernel, ref
+    ks = jax.random.split(jax.random.PRNGKey(4), 2)
+    x = jax.random.normal(ks[0], (M, K), jnp.bfloat16)
+    w = jax.random.normal(ks[1], (3, K, N), jnp.float32)
+    y = kernel.matmul_cast(x, w, jnp.int32(2), block_k=block_k,
+                           block_n=block_n, interpret=True)
+    assert y.shape == (M, N) and y.dtype == jnp.bfloat16
+    r = np.asarray(ref.matmul_cast_ref(x, w[2]), np.float32)
+    # float32 sums in another order can round to the neighbouring bf16,
+    # and seldom do; products of the uncast weight differ in ~40% of them
+    y32 = np.asarray(y, np.float32)
+    np.testing.assert_allclose(y32, r, rtol=2 ** -7,
+                               atol=1e-3 * np.abs(r).max())
+    assert (y32 != r).mean() <= 0.01
+
+
+def test_decode_matmul_unstacked_weight():
+    """A plain (K, N) weight through the public wrapper, which picks the
+    tiles, gives the product of its layer in a stack."""
+    from repro.kernels.decode_matmul import ops
+    ks = jax.random.split(jax.random.PRNGKey(5), 2)
+    x = jax.random.normal(ks[0], (16, 640), jnp.bfloat16)
+    w = jax.random.normal(ks[1], (2, 640, 384), jnp.float32)
+    np.testing.assert_array_equal(np.asarray(ops.decode_matmul(x, w[1])),
+                                  np.asarray(ops.decode_matmul(x, w, 1)))
+
+
+@pytest.mark.parametrize("k,n,tiles", [
+    (1536, 6144, (512, 2048)),
+    (6144, 1536, (512, 1536)),
+    (1536, 1536, (512, 1536)),
+    (200, 300, (200, 300)),
+    (1000, 8000, (512, 2048)),     # no 128-multiple divides either: the
+                                   # last tiles are partial
+])
+def test_decode_matmul_tiles(k, n, tiles):
+    from repro.kernels.decode_matmul import ops
+    assert ops.tiles(k, n, 4) == tiles

@@ -185,3 +185,68 @@ def test_loss_mask_respected():
                   loss_mask=batch2["loss_mask"])
     loss_half2, _ = model.loss_fn(params, batch3, rules={})
     assert np.isclose(float(loss_half), float(loss_half2))  # masked targets ignored
+
+
+def _kernel_calls(fn, *args) -> int:
+    return str(jax.make_jaxpr(fn)(*args)).count("pallas_call")
+
+
+@pytest.mark.parametrize("arch", ["musicgen-medium", "recurrentgemma-9b"])
+def test_decode_matmul_kernel_only_in_decode(arch):
+    """Float32 weights with bf16 compute: decode's attention and MLP
+    matmuls read their weights in the decode_matmul kernel; prefill and a
+    training step, at as few rows, keep the cast-then-dot path, and so does
+    decode where the weights are stored in the compute dtype."""
+    cfg = get_config(arch).smoke()
+    assert cfg.param_dtype == "float32" and cfg.compute_dtype == "bfloat16"
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    b, s = 2, 8  # 16 rows: as few as a decode step's
+    batch = {k: v[:b, :s] for k, v in _batch(cfg).items()}
+    _, cache = model.prefill(params, batch["tokens"], rules={}, max_len=s + 1)
+    tok, pos = batch["tokens"][:, :1], jnp.full((b,), s, jnp.int32)
+
+    def decode(p):
+        return model.decode_step(p, tok, pos, cache, rules={})
+
+    assert _kernel_calls(decode, params) > 0
+    assert _kernel_calls(lambda p: model.prefill(
+        p, batch["tokens"], rules={}, max_len=s + 1), params) == 0
+    assert _kernel_calls(jax.grad(lambda p: model.loss_fn(
+        p, batch, rules={})[0]), params) == 0
+    bf16 = jax.tree.map(lambda a: a.astype(jnp.bfloat16)
+                        if a.dtype == jnp.float32 else a, params)
+    assert _kernel_calls(decode, bf16) == 0
+
+
+def test_float32_weight_decode_matches_precast_bf16():
+    """A decode step over float32 weights (the kernel casts each tile in
+    VMEM) gives the logits of the same step over the same weights cast to
+    bf16 beforehand (XLA's dot, no kernel), to the rounding of a reordered
+    sum."""
+    cfg = dataclasses.replace(get_config("musicgen-medium").smoke(),
+                              n_layers=3)  # two scanned layers, one unrolled
+    model = Model(cfg)
+    params = model.init(jax.random.PRNGKey(11))
+    matrices = ("wq", "wk", "wv", "wo", "wi", "wg")
+    precast = jax.tree_util.tree_map_with_path(
+        lambda path, a: a.astype(jnp.bfloat16)
+        if getattr(path[-1], "key", None) in matrices else a, params)
+    toks = jax.random.randint(jax.random.PRNGKey(12), (B, S + 1), 0,
+                              cfg.vocab_size)
+    _, cache = model.prefill(params, toks[:, :S], rules={}, max_len=S + 1)
+
+    def step(p):
+        return model.decode_step(p, toks[:, S:S + 1],
+                                 jnp.full((B,), S, jnp.int32), cache,
+                                 rules={})[0]
+
+    assert _kernel_calls(step, params) == 12  # 6 in the scan body, 6 after
+    assert _kernel_calls(step, precast) == 0
+    lg = np.asarray(jax.jit(step)(params), np.float32)
+    lg_ref = np.asarray(jax.jit(step)(precast), np.float32)
+    np.testing.assert_allclose(lg, lg_ref, rtol=2 ** -7,
+                               atol=1e-2 * np.abs(lg_ref).max())
+    # a reordered sum seldom moves a logit; products of the uncast weights
+    # move most of them
+    assert (lg != lg_ref).mean() <= 0.01

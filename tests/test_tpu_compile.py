@@ -75,12 +75,24 @@ def test_flash_attention_compiles_at_musicgen_width(one_chip):
     assert "tpu_custom_call" in txt
 
 
-def test_decode_updates_stacked_kv_cache_in_place(topo):
-    """The served decode step at the musicgen cells' shapes (float32
-    parameters, bf16 compute, 16 requests, 750-token cache, donated) writes
-    its new tokens into the stacked caches in place: no copy and no
-    whole-slice write of a stacked cache, and under 3.0 GB of temporaries
-    (with the caches as the layer scan's outputs it takes 6.41 GB)."""
+@pytest.mark.parametrize("rows,k,n", [(16, 1536, 6144), (16, 6144, 1536),
+                                        (16, 1536, 1536)])
+def test_decode_matmul_compiles_at_musicgen_width(one_chip, rows, k, n):
+    """The decode matmul at MusicGen's projection widths, reading one layer
+    of a 48-layer float32 stack."""
+    from repro.kernels.decode_matmul import ops
+    x = jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((48, k, n), jnp.float32, sharding=one_chip)
+    layer = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    txt = _compiled_text(ops.decode_matmul, x, w, layer)
+    assert "tpu_custom_call" in txt
+
+
+@pytest.fixture(scope="module")
+def served_decode(topo):
+    """The served decode step compiled at the musicgen cells' shapes:
+    float32 parameters, bf16 compute, 16 requests, 750-token cache,
+    donated. Returns (cfg, abstract params, B, T, compiled)."""
     from repro.configs import get_config
     from repro.models import Model
     from repro.train import ServeSetup
@@ -103,7 +115,16 @@ def test_decode_updates_stacked_kv_cache_in_place(topo):
     with jax.set_mesh(mesh):
         compiled = jax.jit(setup.decode_fn(), donate_argnums=(1,)).lower(
             params, cache, batch).compile()
+    return cfg, params, B, T, compiled
 
+
+def test_decode_updates_stacked_kv_cache_in_place(served_decode):
+    """The served decode step writes its new tokens into the stacked caches
+    in place: no copy and no whole-slice write of a stacked cache, and
+    under 0.1 GB of temporaries (with the caches as the layer scan's
+    outputs it takes 6.41 GB; with the weights cast outside the kernel,
+    2.76 GB)."""
+    cfg, _, B, T, compiled = served_decode
     stacked = f"bf16[{cfg.n_layers},{B},{T},{cfg.n_kv_heads * cfg.head_dim}]"
     op = re.compile(r"^\s*(?:ROOT )?%(\S+) = " + re.escape(stacked)
                     + r"\S* ([a-z-]+)\(")
@@ -120,4 +141,33 @@ def test_decode_updates_stacked_kv_cache_in_place(topo):
     assert not offenders, offenders
     assert len(inserts) == 2, inserts  # one scatter each into K and V
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < 3.0e9, temp
+    assert temp < 1.0e8, temp
+
+
+def test_decode_reads_float32_weights_in_place(served_decode):
+    """Each of the layer's six weight matmuls reads its float32 matrix
+    straight out of the stacked parameter in the decode_matmul kernel: no
+    value of a weight matrix's shape (a whole-stack cast, a layer's cast
+    or a layer's slice) is written anywhere in the program. (XLA hoisted
+    the casts out of the layer loop as whole-stack converts: 2.72 GB
+    written and read again on every step.)"""
+    cfg, params, _, _, compiled = served_decode
+    L = cfg.n_layers
+    mats = {a.shape[1:] for a in jax.tree.leaves(params["scan"])
+            if a.ndim == 3}
+    assert {(cfg.d_model, cfg.d_ff), (cfg.d_ff, cfg.d_model)} <= mats
+    shapes = "|".join(
+        re.escape(f"[{d}{k},{n}]") for k, n in mats for d in ("", f"{L},"))
+    weight_value = re.compile(
+        r"^\s*(?:ROOT )?%(\S+) = (?:bf16|f32)(?:" + shapes + r")\S* "
+        r"(?!parameter\(|get-tuple-element\(|bitcast\()")
+    txt = compiled.as_text()
+    written = [m.group(1) for m in map(weight_value.match, txt.splitlines())
+               if m is not None]
+    assert not written, written
+    kernels = re.findall(r"%(decode_matmul_\w+?)\.\d+ = .*"
+                         r'custom_call_target="tpu_custom_call"', txt)
+    assert sorted(kernels) == sorted([
+        "decode_matmul_attn_wq", "decode_matmul_attn_wk",
+        "decode_matmul_attn_wv", "decode_matmul_attn_wo",
+        "decode_matmul_mlp_wi", "decode_matmul_mlp_wo"]), kernels
