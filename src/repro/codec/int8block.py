@@ -3,9 +3,10 @@
 The same scheme as ``optim/grad_compress`` but applied to egress datasets:
 each block of 4096 consecutive elements is scaled by ``amax/127`` and
 rounded to int8, shrinking float64 payloads 8x (float32 4x) minus a 4-byte
-scale per block.  The reconstruction error is provably bounded:
-``|x - dq| <= scale/2`` per element (rint is within 1/2 ULP of ``x/scale``
-and ``|x/scale| <= 127`` by construction, so the clip never bites).
+scale per block.  The reconstruction error is bounded per block by
+``|x - dq| <= scale/2 + spacing(amax)``: rint is within 1/2 of ``x/scale``
+and ``|x/scale| <= 127`` by construction, so the clip never bites; the
+float rounding of ``x/scale`` and ``q*scale`` adds the ``spacing`` term.
 
 For jax device arrays the quantize+pack step lowers through the
 ``kernels/staging_pack`` quantizing variant (``ops.quantize_blocks``) so
@@ -23,6 +24,45 @@ import numpy as np
 from .base import Codec, as_bytes_array, np_dtype, register_codec
 
 BLOCK = 4096  # elements per scale block; matches grad_compress.QBLOCK
+
+
+def quantize(x: np.ndarray, block: int) -> Tuple[np.ndarray, np.ndarray]:
+    """Quantize a flat float array in blocks of ``block`` elements.
+
+    Returns ``(scales, q)``: one float32 ``amax/127`` scale per block (1.0
+    for an all-zero block) and ``x.size`` int8 values. Float16 input is
+    computed in float32, which keeps the bound of the module docstring.
+    """
+    n = x.size
+    nb = -(-n // block)
+    scales = np.ones(nb, np.float32)
+    q = np.empty(nb * block, np.int8)
+    if n:
+        # float16 math would wreck the scale/2 bound; compute in >=f32.
+        cdt = x.dtype if x.dtype.itemsize >= 4 else np.dtype(np.float32)
+        xb = np.zeros(nb * block, cdt)
+        xb[:n] = x
+        xb = xb.reshape(nb, block)
+        amax = np.max(np.abs(xb), axis=1)
+        scales = (amax / np.float32(127.0)).astype(np.float32)
+        scales[scales == 0] = 1.0
+        q = np.clip(np.rint(xb / scales[:, None].astype(cdt)),
+                    -127, 127).astype(np.int8).reshape(-1)
+    return scales, q[:n]
+
+
+def dequantize(scales: np.ndarray, q: np.ndarray, dtype,
+               block: int) -> np.ndarray:
+    """Invert :func:`quantize`: ``q.size`` values of ``dtype``, flat."""
+    n = q.size
+    nb = -(-n // block)
+    dt = np.dtype(dtype)
+    qb = np.zeros(nb * block, np.int8)
+    qb[:n] = q
+    cdt = dt if dt.itemsize >= 4 else np.dtype(np.float32)
+    dq = (qb.reshape(nb, block).astype(cdt) *
+          scales[:, None].astype(cdt)).reshape(-1)[:n]
+    return dq.astype(dt)
 
 
 def _device_array(data):
@@ -71,23 +111,9 @@ class Int8BlockCodec(Codec):
             arr = raw.view(dt)
         if arr.dtype.kind != "f" or arr.dtype.itemsize < 2:
             return self._passthrough(as_bytes_array(arr))
-        x = arr.reshape(-1)
-        n = x.size
-        nb = -(-n // BLOCK)
-        scales = np.ones(nb, np.float32)
-        q = np.empty(nb * BLOCK, np.int8)
-        if n:
-            # float16 math would wreck the scale/2 bound; compute in >=f32.
-            cdt = x.dtype if x.dtype.itemsize >= 4 else np.dtype(np.float32)
-            xb = np.zeros(nb * BLOCK, cdt)
-            xb[:n] = x
-            xb = xb.reshape(nb, BLOCK)
-            amax = np.max(np.abs(xb), axis=1)
-            scales = (amax / np.float32(127.0)).astype(np.float32)
-            scales[scales == 0] = 1.0
-            q = np.clip(np.rint(xb / scales[:, None].astype(cdt)),
-                        -127, 127).astype(np.int8).reshape(-1)
-        payload = scales.tobytes() + q[:n].tobytes()
+        n = arr.size
+        scales, q = quantize(arr.reshape(-1), BLOCK)
+        payload = scales.tobytes() + q.tobytes()
         meta = {"raw_size": int(n * arr.dtype.itemsize), "n": int(n),
                 "dtype": arr.dtype.name, "block": BLOCK}
         return payload, meta
@@ -126,10 +152,6 @@ class Int8BlockCodec(Codec):
             raise ValueError(
                 f"int8-block payload is {raw.size}B, expected "
                 f"{nb * 4 + n}B ({nb} scales + {n} values)")
-        scales = raw[:nb * 4].view(np.float32)
-        q = np.zeros(nb * block, np.int8)
-        q[:n] = raw[nb * 4:].view(np.int8)
-        cdt = dt if dt.itemsize >= 4 else np.dtype(np.float32)
-        dq = (q.reshape(nb, block).astype(cdt) *
-              scales[:, None].astype(cdt)).reshape(-1)[:n]
-        return np.ascontiguousarray(dq.astype(dt)).view(np.uint8)
+        dq = dequantize(raw[:nb * 4].view(np.float32),
+                        raw[nb * 4:].view(np.int8), dt, block)
+        return np.ascontiguousarray(dq).view(np.uint8)

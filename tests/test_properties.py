@@ -5,7 +5,7 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core.blocks import TransferCostModel, plan_blocks, vmem_tile
-from repro.core.intransit import dequantize_int8_np, quantize_int8_np
+from repro.codec.int8block import dequantize, quantize
 from repro.core.tars import TAR, Attribute, Dimension
 
 settings.register_profile("ci", max_examples=50, deadline=None)
@@ -53,46 +53,48 @@ def test_vmem_tile_alignment(elems, itemsize):
 # ---------------------------------------------------------------------------
 
 
+def assert_within_bound(x, back, s, block):
+    """The int8-block guarantee, per block: |x - dq| <= scale/2 +
+    spacing(amax), the spacing covering the float32 rounding of x / scale
+    and q * scale."""
+    n = x.size
+    pad = (-n) % block
+    amax = np.abs(np.pad(x, (0, pad))).reshape(-1, block).max(axis=1)
+    bound = np.repeat(s / 2 + np.spacing(amax), block)[:n]
+    assert (np.abs(back - x) <= bound).all()
+
+
 @given(st.integers(1, 5000), st.integers(0, 2 ** 32 - 1))
 def test_int8_quant_error_bound(n, seed):
     rng = np.random.default_rng(seed)
     x = rng.standard_normal(n).astype(np.float32) * rng.uniform(0.01, 100)
     block = 256
-    q, s = quantize_int8_np(x, block)
-    back = dequantize_int8_np(q, s, x.shape, block)
-    # per-block error bound: scale/2 = amax/254, up to the float32
-    # rounding of x / scale and x - q * scale (one ulp of the block's amax)
-    pad = (-n) % block
-    xp = np.pad(x, (0, pad)).reshape(-1, block)
-    amax = np.abs(xp).max(axis=1)
-    err = np.abs(np.pad(x, (0, pad)).reshape(-1, block)
-                 - np.pad(back, (0, pad)).reshape(-1, block))
-    assert (err <= (amax / 127.0 / 2 + np.spacing(amax))[:, None]).all()
+    s, q = quantize(x, block)
+    back = dequantize(s, q, x.dtype, block)
+    assert_within_bound(x, back, s, block)
 
 
 @given(st.integers(1, 2000))
 def test_quant_zero_block_is_exact(n):
     x = np.zeros(n, np.float32)
-    q, s = quantize_int8_np(x, 128)
-    assert (dequantize_int8_np(q, s, x.shape, 128) == 0).all()
+    s, q = quantize(x, 128)
+    assert (dequantize(s, q, x.dtype, 128) == 0).all()
 
 
 @given(n=st.integers(1, 1 << 14), block=st.integers(1, 4096),
        seed=st.integers(0, 2 ** 31 - 1))
 def test_quant_roundtrip_any_size_any_block(n, block, seed):
     """Round trip holds for every (size, block) pairing: odd sizes, blocks
-    larger than the input, and non-divisible quant_block all pad correctly
-    and dequantize back to the original shape within the error bound."""
+    larger than the input, and non-divisible blocks all pad correctly
+    and dequantize back to the original size within the error bound."""
     rng = np.random.default_rng(seed)
     x = rng.standard_normal(n).astype(np.float32)
-    q, s = quantize_int8_np(x, block)
-    pad = (-n) % block
-    assert q.size == n + pad                       # block-padded flat stream
-    assert s.size == (n + pad) // block            # one scale per block
-    back = dequantize_int8_np(q, s, x.shape, block)
-    assert back.shape == x.shape
-    bound = np.repeat(s, block)[:n] / 2 + 1e-7
-    assert (np.abs(back - x) <= bound).all()
+    s, q = quantize(x, block)
+    assert q.size == n                             # one int8 per element
+    assert s.size == -(-n // block)                # one scale per block
+    back = dequantize(s, q, x.dtype, block)
+    assert back.shape == x.shape and back.dtype == x.dtype
+    assert_within_bound(x, back, s, block)
 
 
 @pytest.mark.parametrize("n,block", [
@@ -100,18 +102,17 @@ def test_quant_roundtrip_any_size_any_block(n, block, seed):
     (7, 8),           # odd size one short of the block
     (127, 64),        # odd size spanning two blocks
     (129, 64),        # one element into the third block
-    (4095, 4096),     # default quant_block, one short
-    (4097, 4096),     # default quant_block, one over
+    (4095, 4096),     # the codec's block, one short
+    (4097, 4096),     # the codec's block, one over
     (5000, 333),      # mutually indivisible
 ])
 def test_quant_roundtrip_edge_sizes(n, block):
     rng = np.random.default_rng(n * 31 + block)
     x = (rng.standard_normal(n) * 10).astype(np.float32)
-    q, s = quantize_int8_np(x, block)
-    back = dequantize_int8_np(q, s, x.shape, block)
+    s, q = quantize(x, block)
+    back = dequantize(s, q, x.dtype, block)
     assert back.shape == x.shape
-    bound = np.repeat(s, block)[:n] / 2 + 1e-7
-    assert (np.abs(back - x) <= bound).all()
+    assert_within_bound(x, back, s, block)
 
 
 @given(n=st.integers(1, 2048), block=st.integers(1, 512))
@@ -120,13 +121,14 @@ def test_quant_zero_and_constant_blocks_nondivisible(n, block):
     scale is replaced by 1.0, so padding never produces NaN/Inf), and a
     constant input is recovered exactly (it sits on a quantization level)."""
     z = np.zeros(n, np.float32)
-    q, s = quantize_int8_np(z, block)
+    s, q = quantize(z, block)
     assert np.isfinite(s).all()
-    assert (dequantize_int8_np(q, s, z.shape, block) == 0).all()
+    assert (dequantize(s, q, z.dtype, block) == 0).all()
     c = np.full(n, 3.25, np.float32)
-    q, s = quantize_int8_np(c, block)
-    back = dequantize_int8_np(q, s, c.shape, block)
+    s, q = quantize(c, block)
+    back = dequantize(s, q, c.dtype, block)
     assert np.allclose(back, c, rtol=1e-6, atol=0)
+    assert_within_bound(c, back, s, block)
 
 
 # ---------------------------------------------------------------------------

@@ -103,18 +103,20 @@ def test_intransit_sink_roundtrip(savime, staging):
 
 
 def test_intransit_sink_quantized(savime, staging):
-    from repro.core.intransit import dequantize_int8_np
+    """The int8-block codec through the sink: SAVIME answers floats within
+    the codec's bound, |x - dq| <= scale/2 + spacing(amax) per block."""
+    from repro.codec.int8block import BLOCK, quantize
     sink = InTransitSink(staging.addr,
-                         InTransitConfig(quantize="int8", tar_prefix="q"))
+                         InTransitConfig(codec="int8-block", tar_prefix="q"))
     x = np.random.default_rng(3).standard_normal((32, 32)).astype(np.float32)
     sink.stage_array("act", x, step=0)
     sink.flush()
-    direct = SavimeClient(savime.addr)
-    q = direct.run("select(q_act, v)")
-    s = direct.run("select(q_act__scale, s)")
-    deq = dequantize_int8_np(q[0], s[0][: max(q[0].size // 4096, 1)],
-                             x.shape, 4096)
-    assert np.abs(deq - x).max() <= np.abs(x).max() / 127 + 1e-6
+    got = SavimeClient(savime.addr).run("select(q_act, v)")[0]
+    assert got.dtype == np.float32 and got.size == x.size
+    (s,), _ = quantize(x.reshape(-1), BLOCK)       # one block of 1024
+    err = np.abs(got.reshape(x.shape) - x)
+    assert err.max() <= s / 2 + np.spacing(np.abs(x).max())
+    assert err.max() > 0                            # it was quantized
     sink.close()
 
 
